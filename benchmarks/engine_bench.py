@@ -737,6 +737,9 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     print("name,us_per_call,derived")
     if args.smoke:
         smoke()

@@ -37,6 +37,8 @@ def main() -> None:
                     help="small CI subset: kernel modes + engine smoke")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
 

@@ -47,6 +47,8 @@ def main():
     ap.set_defaults(max_batch=4, max_seq=96, quant="luna_approx")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     model_mode = (args.quant if args.quant not in ENGINE_QUANT_MODES
                   else "bf16")
     cfg = get_config("yi-9b").reduced(quant=QuantConfig(mode=model_mode))

@@ -229,15 +229,20 @@ def quantize_weight(w: jax.Array, kernel: str = "lut_dc",
     pruned below ``prune_threshold`` when given (``quant="nf4p"``).
 
     Leaves with extra leading axes (scan-stacked layers) are quantized
-    per-slice by vmapping, so every array child carries the same leading
-    axes and the container remains ``jax.lax.scan``-sliceable.
+    one slice at a time and stacked, so every array child carries the same
+    leading axes and the container remains ``jax.lax.scan``-sliceable.
     """
     if kernel not in WEIGHT_KERNELS:
         raise ValueError(f"unknown weight kernel {kernel!r}; "
                          f"one of {WEIGHT_KERNELS}")
     if w.ndim > 2:
-        return jax.vmap(
-            lambda wi: quantize_weight(wi, kernel, prune_threshold))(w)
+        # sequential on purpose: the NF4 encode's (K, N, 16) f32 distance
+        # temporary exists for one slice only (a vmap over 38 full-width
+        # Mamba2 w_in slices would need 42 GB), and each slice is bitwise
+        # the 2-D encode (a lax.map under jit moves scales by an ulp)
+        slices = [quantize_weight(w[i], kernel, prune_threshold)
+                  for i in range(w.shape[0])]
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *slices)
     wf = w.astype(jnp.float32)
     if kernel in ("nf4_dc", "nf4_dequant"):
         from repro.core.lut import NF4_CODEBOOK

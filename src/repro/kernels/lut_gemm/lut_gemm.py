@@ -25,8 +25,8 @@ kernels implement the paper's two select-tree organizations:
 
 Memory layout per grid step: x tile (bm, bk) bf16/f32, packed codes tile
 (bk, bn) int8, dequantized tile (bk, bn) f32 (transient), accumulator
-(bm, bn) f32 in VMEM scratch.  Per-output-channel scales are applied in the
-epilogue on the final K step.
+(bm, bn) f32 in VMEM scratch; the lookup tables whole in SMEM.
+Per-output-channel scales are applied in the epilogue on the final K step.
 """
 from __future__ import annotations
 
@@ -41,14 +41,20 @@ DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 256
 
+#: lookup tables (codebook, D&C sub-tables, residual) live whole in SMEM:
+#: the mux trees read them as scalar leaves, which VMEM cannot serve
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
 
 def _mux_tree_dequant(codes: jax.Array, cb_ref) -> jax.Array:
     """Paper's mux tree: 15 binary selects on the 4 code bits.
 
-    ``codes``: (bk, bn) int8 in [0, 16); ``cb_ref``: (1, 16) codebook.
+    ``codes``: (bk, bn) int32 in [0, 16) (widened from the int8 tile: the
+    TPU vector unit has no int8 shifts); ``cb_ref``: (1, 16) codebook in
+    SMEM, read as scalar leaves.
     """
     leaves = [cb_ref[0, j] for j in range(16)]   # scalar leaves
-    bits = [((codes >> b) & 1).astype(bool) for b in range(4)]
+    bits = [((codes >> b) & 1) != 0 for b in range(4)]
     level = leaves
     for b in range(4):                            # 8 + 4 + 2 + 1 = 15 selects
         level = [jnp.where(bits[b], level[2 * i + 1], level[2 * i])
@@ -64,7 +70,7 @@ def _lut_gemm_kernel(x_ref, codes_ref, cb_ref, scale_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _mux_tree_dequant(codes_ref[...], cb_ref)          # (bk, bn) f32
+    w = _mux_tree_dequant(codes_ref[...].astype(jnp.int32), cb_ref)
     x = x_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -95,7 +101,7 @@ def lut_gemm(x: jax.Array, w_codes: jax.Array, codebook: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 16), lambda i, j, kk: (0, 0)),
+            _SMEM,
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -108,13 +114,13 @@ def lut_gemm(x: jax.Array, w_codes: jax.Array, codebook: jax.Array,
 def _dc_mux_dequant(codes: jax.Array, hi_ref, lo_ref) -> jax.Array:
     """Paper's D&C select tree: 3 + 3 binary selects on the 2-bit digits.
 
-    ``codes``: (bk, bn) int8 in [0, 16); ``hi_ref``/``lo_ref``: (1, 4)
-    code-space sub-tables.  Returns ``HI[codes >> 2] + LO[codes & 3]``.
+    ``codes``: (bk, bn) int32 in [0, 16); ``hi_ref``/``lo_ref``: (1, 4)
+    code-space sub-tables in SMEM.  Returns ``HI[codes >> 2] + LO[codes & 3]``.
     """
     def sel4(idx, tab_ref):
         leaves = [tab_ref[0, j] for j in range(4)]
-        b0 = (idx & 1).astype(bool)
-        b1 = ((idx >> 1) & 1).astype(bool)
+        b0 = (idx & 1) != 0
+        b1 = ((idx >> 1) & 1) != 0
         lo = jnp.where(b0, leaves[1], leaves[0])
         hi = jnp.where(b0, leaves[3], leaves[2])
         return jnp.where(b1, hi, lo)
@@ -130,7 +136,7 @@ def _lut_gemm_dc_kernel(x_ref, codes_ref, hi_ref, lo_ref, zp_ref, scale_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_q = _dc_mux_dequant(codes_ref[...], hi_ref, lo_ref)   # (bk, bn) f32
+    w_q = _dc_mux_dequant(codes_ref[...].astype(jnp.int32), hi_ref, lo_ref)
     w = w_q - zp_ref[...]                                   # (1, bn) bcast
     x = x_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
@@ -149,7 +155,7 @@ def _lut_gemm_dc_res_kernel(x_ref, codes_ref, hi_ref, lo_ref, res_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = codes_ref[...]
+    codes = codes_ref[...].astype(jnp.int32)
     # 6-select D&C mux, then the per-code residual gather (a 16:1 select
     # on the residual table — narrow storage in CIM, zeros where pruned)
     w_q = (_dc_mux_dequant(codes, hi_ref, lo_ref)
@@ -196,9 +202,9 @@ def lut_gemm_dc_res(x: jax.Array, w_codes: jax.Array, hi_tab: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 4), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((1, 4), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((1, 16), lambda i, j, kk: (0, 0)),
+            _SMEM,
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
@@ -233,8 +239,8 @@ def lut_gemm_dc(x: jax.Array, w_codes: jax.Array, hi_tab: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 4), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((1, 4), lambda i, j, kk: (0, 0)),
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],

@@ -80,15 +80,13 @@ def quantized_matmul(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def nf4_matmul_kernel(x: jax.Array, w: jax.Array,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Float GEMM with NF4 codebook weights through the Pallas LUT kernel."""
     cb = jnp.asarray(NF4_CODEBOOK)
     codes, scale = codebook_quantize(w, cb)
     m, k = x.shape
     n = w.shape[1]
-    bm = _fit(m)
-    bn = _fit(n)
-    bk = _fit(k)
+    bm, bn, bk = gemm_blocks(m, k, n)
     xp = jnp.pad(x, [(0, (-m) % bm), (0, (-k) % bk)])
     cp = jnp.pad(codes, [(0, (-k) % bk), (0, (-n) % bn)])
     sp = jnp.pad(scale, [(0, (-n) % bn)])
@@ -98,7 +96,7 @@ def nf4_matmul_kernel(x: jax.Array, w: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def lut4_matmul_kernel(x: jax.Array, w: jax.Array,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """Float GEMM with uniform-int4 weights through the D&C Pallas kernel.
 
     Quantizes ``w`` with :func:`~repro.core.quant.quantize_weight` (the same
@@ -108,9 +106,7 @@ def lut4_matmul_kernel(x: jax.Array, w: jax.Array,
     qw = quantize_weight(w, kernel="lut_dc")
     m, k = x.shape
     n = w.shape[1]
-    bm = _fit(m)
-    bn = _fit(n)
-    bk = _fit(k)
+    bm, bn, bk = gemm_blocks(m, k, n)
     xp = jnp.pad(x, [(0, (-m) % bm), (0, (-k) % bk)])
     cp = jnp.pad(qw.codes, [(0, (-k) % bk), (0, (-n) % bn)])
     zp = jnp.pad(qw.zero_point, [(0, (-n) % bn)])
@@ -123,7 +119,7 @@ def lut4_matmul_kernel(x: jax.Array, w: jax.Array,
 @functools.partial(jax.jit, static_argnames=("prune_threshold", "interpret"))
 def nf4dc_matmul_kernel(x: jax.Array, w: jax.Array,
                         prune_threshold: float | None = None,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """Float GEMM with NF4 weights through the residual-corrected D&C
     Pallas kernel (6-select mux + per-code residual epilogue).
 
@@ -136,9 +132,7 @@ def nf4dc_matmul_kernel(x: jax.Array, w: jax.Array,
     qw = quantize_weight(w, kernel="nf4_dc", prune_threshold=prune_threshold)
     m, k = x.shape
     n = w.shape[1]
-    bm = _fit(m)
-    bn = _fit(n)
-    bk = _fit(k)
+    bm, bn, bk = gemm_blocks(m, k, n)
     xp = jnp.pad(x, [(0, (-m) % bm), (0, (-k) % bk)])
     cp = jnp.pad(qw.codes, [(0, (-k) % bk), (0, (-n) % bn)])
     zp = jnp.pad(qw.zero_point, [(0, (-n) % bn)])
@@ -148,7 +142,15 @@ def nf4dc_matmul_kernel(x: jax.Array, w: jax.Array,
     return out[:m, :n]
 
 
-def _fit(d: int, base: int = 8) -> int:
+def gemm_blocks(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """``(bm, bn, bk)`` for an (m, k) @ (k, n) LUT GEMM; callers pad up
+    to them.  Powers of two up to 256, never below one TPU tile: 8
+    sublanes for ``bm``, 128 lanes for ``bn`` and ``bk`` (``bk`` is also
+    the int8 code tile's sublane dim, which needs 32)."""
+    return _fit(m, 8), _fit(n, 128), _fit(k, 128)
+
+
+def _fit(d: int, base: int) -> int:
     b = base
     while b * 2 <= d and b < 256:
         b *= 2

@@ -11,7 +11,7 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunked_kernel(x, dt, a, b, c, *, chunk: int = 128,
-                       interpret: bool = True, initial_state=None,
+                       interpret: bool = False, initial_state=None,
                        mask=None):
     """x: (B,S,H,P); dt: (B,S,H); a: (H,); b/c: (B,S,G,N) with G|H.
 

@@ -29,6 +29,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, s0_ref, y_ref, fs_ref,
                 state_ref, *, nc: int, q: int):
     ic = pl.program_id(1)
@@ -41,19 +44,34 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, s0_ref, y_ref, fs_ref,
     dt = dt_ref[0].astype(jnp.float32)        # (Q, 1)
     bmat = b_ref[0].astype(jnp.float32)       # (Q, N)
     cmat = c_ref[0].astype(jnp.float32)       # (Q, N)
-    a = a_ref[0, 0]                           # scalar A (negative)
+    a = a_ref[pl.program_id(0)]               # scalar A (negative)
 
-    da_cum = jnp.cumsum(dt[:, 0] * a)[:, None]          # (Q, 1)
+    # inclusive prefix sum of dt*A as a lower-triangular matmul (the TPU
+    # kernel lowering has no cumsum), once as a column and once as a row
+    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    causal = rows >= cols
+    tri = causal.astype(jnp.float32)
+    dta = dt * a                                        # (Q, 1)
+    da_cum = jax.lax.dot_general(
+        tri, dta, (((1,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32)             # (Q, 1)
+    da_row = jax.lax.dot_general(
+        dta, tri, (((0,), (1,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32)             # (1, Q)
+    da_last = da_cum[q - 1:q, :]                        # (1, 1)
     seg_start = jnp.exp(da_cum)                         # (Q, 1)
-    seg_end = jnp.exp(da_cum[-1:] - da_cum)             # (Q, 1)
-    chunk_decay = jnp.exp(da_cum[-1, 0])
+    seg_end = jnp.exp(da_last - da_cum)                 # (Q, 1)
+    # the whole-chunk decay as a (1, P) row (the lowering cannot broadcast
+    # a (1, 1) value over both axes of the (N, P) state)
+    da_total = jax.lax.dot_general(
+        dta, jnp.ones_like(x), (((0,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32)             # (1, P)
+    chunk_decay = jnp.exp(da_total)
     xdt = x * dt                                        # (Q, P)
 
     # intra-chunk: L[i,j] = exp(da_cum[i]-da_cum[j]) for i >= j
-    rel = da_cum - da_cum[:, 0][None, :]                # (Q, Q)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.exp(jnp.where(rows >= cols, rel, -1e30))
+    L = jnp.exp(jnp.where(causal, da_cum - da_row, -1e30))
     cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q, Q)
     y = jax.lax.dot_general(cb * L, xdt, (((1,), (0,)), ((), ())),
@@ -107,7 +125,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
             pl.BlockSpec((1, chunk, 1), lambda i, ic: (i, ic, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, ic: (i, ic, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, ic: (i, ic, 0)),
-            pl.BlockSpec((1, 1), lambda i, ic: (i, 0)),
+            # every stream's scalar decay rate, whole in SMEM
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, n, p), lambda i, ic: (i, 0, 0)),
         ],
         out_specs=[
@@ -120,6 +139,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dt[..., None], b, c, a[:, None],
+    )(x, dt[..., None], b, c, a,
       initial_state.astype(jnp.float32))
     return y, fs

@@ -1,7 +1,11 @@
 """Serving CLI: batched requests against any assigned arch (reduced or full).
 
-  PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --reduced \
+  PYTHONPATH=src python -m repro.launch.serve --arch yi-9b \
       --quant luna_approx --requests 8 --sampling top_k --top-k 40
+
+  # published widths (the default is the reduced smoke size); on a TPU:
+  PYTHONPATH=src python -m repro.launch.serve --arch zamba2-1.2b \
+      --no-reduced --paged --max-seq 256
 
   # LUT-quantized decode hot path (engine-level, D&C sub-table gemm):
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --quant lut4
@@ -26,6 +30,9 @@ The CLI serves from the BACKGROUND LOOP by default (``engine.start()``,
 one ``submit()`` per request, streams consumed off the loop thread,
 ``engine.stop()`` drains) — the same path a network front-end would use.
 ``--sync`` keeps the old caller-pumped ``engine.serve(requests)`` path.
+The whole path — config, parameters built on the device by a jitted
+``model.init``, engine, serving — is :func:`serve`, which ``chip_smoke.py``
+drives too.
 
 Observability (see docs/observability.md): ``--metrics-port`` serves the
 engine's metrics registry as a Prometheus scrape endpoint while the run
@@ -36,14 +43,93 @@ JSON on exit (open at https://ui.perfetto.dev).
 from __future__ import annotations
 
 import argparse
+import time
+from dataclasses import dataclass
+
+
+@dataclass
+class ServeRun:
+    """What :func:`serve` built and what it measured."""
+    engine: object
+    streams: list        # per request, the tokens its handle streamed
+    stats: dict          # engine summary + ``wall_s`` and ``done``
+    setup_s: float       # parameter init (if built here) + engine build
+
+
+def init_params(cfg, seed: int = 0):
+    """Random parameters made on the device by one jitted init (no host
+    copy of the full-width tree)."""
+    import jax
+
+    from repro.models.registry import get_model
+    return jax.jit(get_model(cfg).init)(jax.random.PRNGKey(seed))
+
+
+def serve(cfg, engine_config, requests, *, params=None, sync: bool = False,
+          metrics_port: int | None = None) -> ServeRun:
+    """Build params (unless given: :func:`init_params`) and an engine,
+    then serve ``requests``.
+
+    By default through the background loop — ``engine.start()``, one
+    ``submit()`` per request, each handle's ``tokens()`` drained on its
+    own client thread, ``engine.stop()`` — the path a network front end
+    uses; ``sync=True`` pumps ``engine.serve(requests)`` instead (streams
+    are then the final outputs).  ``metrics_port`` serves the engine's
+    metrics registry while the requests run.
+    """
+    import jax
+
+    from repro.serve.engine import Engine
+
+    t0 = time.perf_counter()
+    if params is None:
+        params = init_params(cfg)
+    engine = Engine(cfg, params, engine_config)
+    jax.block_until_ready(engine.decode_params)
+    setup_s = time.perf_counter() - t0
+
+    metrics_server = None
+    if metrics_port is not None:
+        from repro.obs import start_metrics_server
+        metrics_server = start_metrics_server(engine.registry, metrics_port)
+        print(f"metrics: http://127.0.0.1:"
+              f"{metrics_server.server_address[1]}/metrics")
+    try:
+        if sync:
+            stats = engine.serve(requests)
+            streams = [list(r.out) for r in requests]
+        else:
+            from concurrent.futures import ThreadPoolExecutor
+
+            start = engine.metrics.snapshot()
+            t1 = engine.clock()
+            engine.start()
+            try:
+                handles = [engine.submit(r) for r in requests]
+                with ThreadPoolExecutor(
+                        max_workers=min(8, len(handles))) as pool:
+                    streams = list(pool.map(lambda h: list(h.tokens()),
+                                            handles))
+            finally:
+                engine.stop()
+            stats = engine.metrics.since(start).summary(engine.max_batch)
+            stats.update({"wall_s": engine.clock() - t1,
+                          "done": all(r.done for r in requests)})
+    finally:
+        if metrics_server is not None:
+            metrics_server.shutdown()
+    return ServeRun(engine, streams, stats, setup_s)
 
 
 def main():
-    from repro.serve.config import ENGINE_QUANT_MODES, EngineConfig
+    from repro.serve.config import EngineConfig
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-9b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-size config (default); --no-reduced serves "
+                         "the published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--sync", action="store_true",
@@ -55,54 +141,35 @@ def main():
 
     from dataclasses import replace
 
-    import jax
     import numpy as np
 
     from repro.core.layers import QuantConfig
-    from repro.models.registry import get_config, get_model
-    from repro.serve.engine import Engine, Request
+    from repro.launch.cache import enable_compile_cache
+    from repro.models.registry import get_config
+    from repro.serve.config import ENGINE_QUANT_MODES
+    from repro.serve.engine import Request
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     if args.quant not in ("bf16", *ENGINE_QUANT_MODES):
         cfg = replace(cfg, quant=QuantConfig(mode=args.quant))
-
-    model = get_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    engine = Engine(cfg, params, EngineConfig.from_args(args))
-    metrics_server = None
-    if args.metrics_port is not None:
-        from repro.obs import start_metrics_server
-        metrics_server = start_metrics_server(engine.registry,
-                                              args.metrics_port)
-        print(f"metrics: http://127.0.0.1:"
-              f"{metrics_server.server_address[1]}/metrics")
     rng = np.random.default_rng(0)
     reqs = [Request(rid=i,
                     prompt=rng.integers(1, cfg.vocab_size, 6).tolist(),
                     max_new=args.max_new)
             for i in range(args.requests)]
-    if args.sync:
-        stats = engine.serve(reqs)
-    else:
-        from concurrent.futures import ThreadPoolExecutor
-
-        start = engine.metrics.snapshot()
-        t0 = engine.clock()
-        engine.start()
-        handles = [engine.submit(r) for r in reqs]
-        with ThreadPoolExecutor(max_workers=min(8, len(handles))) as pool:
-            streams = list(pool.map(lambda h: list(h.tokens()), handles))
-        engine.stop()
-        for r, s in zip(reqs, streams):
-            assert s == r.out, f"rid {r.rid}: stream diverged from out"
-        stats = engine.metrics.since(start).summary(engine.max_batch)
-        stats.update({"wall_s": engine.clock() - t0,
-                      "done": all(r.done for r in reqs)})
+    run = serve(cfg, EngineConfig.from_args(args), reqs, sync=args.sync,
+                metrics_port=args.metrics_port)
+    engine, stats = run.engine, run.stats
+    for r, s in zip(reqs, run.streams):
+        if s != r.out:
+            raise RuntimeError(f"rid {r.rid}: stream diverged from out")
     tok_count = sum(len(r.out) for r in reqs)
     print(f"{tok_count} tokens over {len(reqs)} requests: "
-          f"{stats['wall_s']:.2f}s wall, done={stats['done']}")
+          f"{stats['wall_s']:.2f}s wall, done={stats['done']} "
+          f"(set-up {run.setup_s:.2f}s)")
     print(f"  prefill: {stats['prefill_tokens']} tok in "
           f"{stats['prefill_s']:.2f}s ({stats['prefill_tok_s']:.0f} tok/s, "
           f"{stats['prefill_calls']} bucket calls)")
@@ -119,8 +186,6 @@ def main():
           f"{stats['preemptions']} preempted")
     print(f"  deadlines: {stats['deadline_hits']} hit, "
           f"{stats['deadline_misses']} missed")
-    if metrics_server is not None:
-        metrics_server.shutdown()
     if args.metrics_dump:
         from repro.obs import dump_metrics
         dump_metrics(engine.registry, args.metrics_dump)

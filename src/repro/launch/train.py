@@ -39,6 +39,9 @@ def main():
     if args.distributed:
         jax.distributed.initialize()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.core.layers import QuantConfig
     from repro.data.synthetic import SyntheticLM
     from repro.launch.mesh import make_host_mesh

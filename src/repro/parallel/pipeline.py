@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -75,4 +75,4 @@ def pipeline_apply(stage_fn, stage_params, x_micro, *, mesh,
                 P())
     return shard_map(
         per_pod, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check_rep=False)(stage_params, x_micro)
+        check_vma=False)(stage_params, x_micro)

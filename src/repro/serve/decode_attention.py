@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import batch_axes
@@ -191,7 +191,7 @@ def sharded_gqa_decode(q, k_cache, v_cache, k_new, v_new, index, mesh,
             in_specs=(rep, pool_spec, pool_spec, rep, rep, P(None),
                       P(None, None)),
             out_specs=(rep, pool_spec, pool_spec),
-            check_rep=False,
+            check_vma=False,
         )(q, k_cache, v_cache, k_new, v_new, idx, block_table)
         return out, k_cache, v_cache
 
@@ -218,7 +218,7 @@ def sharded_gqa_decode(q, k_cache, v_cache, k_new, v_new, index, mesh,
         in_specs=(io_spec, cache_spec, cache_spec, io_spec, io_spec,
                   idx_spec),
         out_specs=(io_spec, cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, k_new, v_new, index)
     return out, k_cache, v_cache
 
@@ -279,7 +279,7 @@ def sharded_mla_decode(q_abs, q_rope, c_cache, r_cache, c_new, r_new, index,
             in_specs=(qrep, qrep, pool_spec, pool_spec, P(None, None, None),
                       P(None, None, None), P(None), P(None, None)),
             out_specs=(qrep, pool_spec, pool_spec),
-            check_rep=False,
+            check_vma=False,
         )(q_abs, q_rope, c_cache, r_cache, c_new, r_new, idx, block_table)
         return ctx, c_cache, r_cache
 
@@ -304,6 +304,6 @@ def sharded_mla_decode(q_abs, q_rope, c_cache, r_cache, c_new, r_new, index,
         in_specs=(qspec, qspec, cache_spec, cache_spec,
                   P(ba, None, None), P(ba, None, None), idx_spec),
         out_specs=(qspec, cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )(q_abs, q_rope, c_cache, r_cache, c_new, r_new, index)
     return ctx, c_cache, r_cache

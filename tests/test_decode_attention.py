@@ -14,10 +14,11 @@ GQA_CODE = textwrap.dedent("""
     import sys; sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.models.registry import get_config, get_model
+    from repro.launch.mesh import make_mesh
     from repro.parallel.act_sharding import activation_sharding
     from dataclasses import replace
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = get_config(%(arch)r).reduced(dtype="float32", attn_impl="full")
     rng = np.random.default_rng(0)
     toks = jnp.asarray(rng.integers(0, base.vocab_size, (2, 8)))
@@ -69,11 +70,12 @@ PAGED_CODE = textwrap.dedent("""
     import sys; sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.models.registry import get_config, get_model
+    from repro.launch.mesh import make_mesh
     from repro.parallel.act_sharding import activation_sharding
     from dataclasses import replace
     import contextlib
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = get_config(%(arch)r).reduced(dtype="float32", attn_impl="full")
     rng = np.random.default_rng(0)
     toks = jnp.asarray(rng.integers(0, base.vocab_size, (2, 8)))

@@ -175,6 +175,31 @@ def test_quantized_weight_slices_under_scan():
     np.testing.assert_array_equal(np.asarray(ys), np.asarray(per_layer))
 
 
+@pytest.mark.parametrize("kernel", ["lut_dc", "nf4_dc"])
+def test_stacked_encode_equals_per_slice_encode(kernel):
+    """A stacked (L, K, N) leaf encodes slice by slice: every child is
+    bitwise the stack of the 2-D encode of each slice, and of the
+    whole-stack vmap encode it replaced."""
+    rng = np.random.default_rng(5)
+    ws = jnp.asarray(rng.normal(size=(4, 48, 40)), jnp.float32)
+    qs = quantize_weight(ws, kernel)
+    per_slice = [quantize_weight(ws[i], kernel) for i in range(4)]
+    vmapped = jax.vmap(lambda wi: quantize_weight(wi, kernel))(ws)
+    assert qs.kernel == kernel
+    for name in ("codes", "scale", "zero_point", "hi_tab", "lo_tab",
+                 "residual"):
+        got = getattr(qs, name)
+        if got is None:
+            assert kernel == "lut_dc" and per_slice[0].residual is None
+            continue
+        want = np.stack([np.asarray(getattr(q, name)) for q in per_slice])
+        assert got.dtype == want.dtype, name
+        np.testing.assert_array_equal(np.asarray(got), want, err_msg=name)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(getattr(vmapped, name)),
+                                      err_msg=name)
+
+
 # ---------------------------------------------------------------------------
 # engine behavior under quant
 # ---------------------------------------------------------------------------

@@ -279,7 +279,11 @@ def test_submit_backpressure_queues_under_loop():
     try:
         reqs = [Request(rid=i, prompt=list(p), max_new=4)
                 for i, p in enumerate(_prompts(cfg, lens=(6, 6, 6)))]
-        handles = [loop.submit(r) for r in reqs]
+        # hold the engine lock across the submits so the loop cannot retire
+        # the first request before the others arrive (on a loaded host it
+        # otherwise can, and nothing backpressures)
+        with loop._lock:
+            handles = [loop.submit(r) for r in reqs]
         assert not all(handles), "3 requests on 1 slot must backpressure"
         outs = _consume_threaded(handles)
         assert all(len(o) == 4 for o in outs)

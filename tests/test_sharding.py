@@ -79,12 +79,13 @@ MINI_DRYRUN = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.models.registry import get_config, get_model, input_specs
     from repro.parallel import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.parallel.act_sharding import activation_sharding
     from repro.optim.adamw import AdamW, AdamWState
     from repro.train.train_step import make_train_step
     from repro.configs.base import ShapeConfig
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     cfg = get_config(%(arch)r).reduced(num_layers=2, d_model=256,
                                        num_heads=8, d_ff=512, head_dim=32)
     model = get_model(cfg)
@@ -113,8 +114,6 @@ MINI_DRYRUN = textwrap.dedent("""
                                       shd.scalar_sharding(mesh))
                         ).lower(params_shape, tok, cache_shape, idx).compile()
     ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jaxlib: one dict per program
-        ca = ca[0]
     print("COMPILED", ca.get("flops", 0) > 0)
 """)
 

@@ -215,16 +215,17 @@ def test_quantized_psum_multidevice():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.parallel.collectives import quantized_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         x = jnp.asarray(np.random.default_rng(0).normal(
             size=(8, 32)).astype(np.float32))
         def f(x):
             return quantized_psum(x, "data")
         got = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                        check_rep=False)(x)
+                        check_vma=False)(x)
         ref = jnp.broadcast_to(x.sum(0, keepdims=True), x.shape)
         rel = float(jnp.abs(got - ref).max() / jnp.abs(ref).max())
         assert rel < 0.03, rel
@@ -244,8 +245,9 @@ def test_pipeline_matches_sequential():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.parallel.pipeline import pipeline_apply
-        mesh = jax.make_mesh((2,), ("pod",))
+        mesh = make_mesh((2,), ("pod",))
         rng = np.random.default_rng(0)
         W = jnp.asarray(rng.normal(size=(2, 16, 16)).astype(np.float32)) * 0.3
         xs = jnp.asarray(rng.normal(size=(4, 3, 16)).astype(np.float32))

@@ -1,0 +1,116 @@
+"""The Pallas kernels of the serving models compile for a TPU v5e chip.
+
+Each test lowers one kernel at zamba2-1.2b's real widths and compiles it
+for a *described* ``v5e:2x2`` topology: the TPU compiler runs here, no chip
+is attached and nothing executes.  This catches what interpret mode cannot
+— tiling, memory-space and lowering refusals — at no chip time.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+and every test worker imports every test file.  Keep these tests in this
+one file, so that one worker owns the library.
+"""
+import inspect
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.flash_attention.flash_attention import flash_attention
+from repro.kernels.lut_gemm import ops as lut_ops
+from repro.kernels.lut_gemm.lut_gemm import lut_gemm_dc, lut_gemm_dc_res
+from repro.kernels.ssd_scan import ops as ssd_ops
+from repro.kernels.ssd_scan.ssd_scan import ssd_scan
+
+#: zamba2-1.2b widths: d_model 2048, Mamba2 in-projection 2048 -> 8384,
+#: shared MLP 2048 -> 8192; SSD heads of P=64 with state N=64, chunk 256;
+#: shared attention 32 heads of d=64
+LUT_SHAPES = [(2048, 8384), (2048, 8192)]
+DECODE_M = 8
+SSD_HEADS, SSD_P, SSD_N, SSD_CHUNK, SSD_SEQ = 64, 64, 64, 256, 512
+ATTN_HEADS, ATTN_D, ATTN_SEQ = 32, 64, 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one: keep these out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory placed on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("kernel", ["lut_gemm_dc", "lut_gemm_dc_res"])
+@pytest.mark.parametrize("k,n", LUT_SHAPES)
+def test_lut_gemm_compiles_for_v5e(sds, kernel, k, n):
+    """Decode-size LUT GEMMs with the block sizes the wrappers choose."""
+    bm, bn, bk = lut_ops.gemm_blocks(DECODE_M, k, n)
+    mp = DECODE_M + (-DECODE_M) % bm
+    kp, np_ = k + (-k) % bk, n + (-n) % bn
+    f32 = jnp.float32
+    x = sds((mp, kp), f32)
+    codes = sds((kp, np_), jnp.int8)
+    tab4, chan = sds((4,), f32), sds((np_,), f32)
+    if kernel == "lut_gemm_dc":
+        _compile(lambda *a: lut_gemm_dc(*a, bm=bm, bn=bn, bk=bk),
+                 x, codes, tab4, tab4, chan, chan)
+    else:
+        _compile(lambda *a: lut_gemm_dc_res(*a, bm=bm, bn=bn, bk=bk),
+                 x, codes, tab4, tab4, sds((16,), f32), chan, chan)
+
+
+def test_ssd_scan_compiles_for_v5e(sds):
+    """The resumable, masked SSD chunk scan at one sequence's heads."""
+    bh, f32 = SSD_HEADS, jnp.float32
+    _compile(lambda x, dt, a, b, c, s0, m: ssd_scan(
+        x, dt, a, b, c, chunk=SSD_CHUNK, initial_state=s0, mask=m),
+        sds((bh, SSD_SEQ, SSD_P), f32), sds((bh, SSD_SEQ), f32),
+        sds((bh,), f32), sds((bh, SSD_SEQ, SSD_N), f32),
+        sds((bh, SSD_SEQ, SSD_N), f32), sds((bh, SSD_N, SSD_P), f32),
+        sds((bh, SSD_SEQ), jnp.bool_))
+
+
+def test_flash_attention_compiles_for_v5e(sds):
+    """Causal flash attention with the blocks ``ops.mha`` picks."""
+    qkv = sds((ATTN_HEADS, ATTN_SEQ, ATTN_D), jnp.bfloat16)
+    _compile(lambda q, k, v: flash_attention(
+        q, k, v, sm_scale=ATTN_D ** -0.5, causal=True,
+        num_q_heads=ATTN_HEADS, num_kv_heads=ATTN_HEADS,
+        bq=min(256, ATTN_SEQ), bkv=min(512, ATTN_SEQ)), qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("fn", [
+    lut_ops.nf4_matmul_kernel, lut_ops.lut4_matmul_kernel,
+    lut_ops.nf4dc_matmul_kernel, ssd_ops.ssd_chunked_kernel,
+    lut_gemm_dc, lut_gemm_dc_res, ssd_scan, flash_attention,
+], ids=lambda f: f.__name__)
+def test_kernels_do_not_default_to_interpret(fn):
+    """On a chip a kernel compiles unless its caller asks for the
+    interpreter; CPU callers pass ``interpret=True`` explicitly."""
+    default = inspect.signature(fn).parameters["interpret"].default
+    assert default is not True
