@@ -2,12 +2,17 @@
 
 The engine emits one structured event per lifecycle transition —
 ``submit -> queue -> admit -> prefill_chunk* -> first_token -> token* ->
-preempt/cancel -> finish`` — plus complete-span events for the per-tick
-engine phases (``admit``/``prefill``/``decode``/``emit``).  Every stamp
-comes from the clock injected at construction (the engine's single time
-base), which is what makes virtual-clock load-harness traces
-byte-identical across repeated runs: no wall time ever leaks into an
-event.
+preempt/cancel -> finish`` — plus complete-span events for the engine
+phases (:data:`PHASE_EVENTS`).  Every stamp comes from the clock
+injected at construction (the engine's single time base), which is what
+makes virtual-clock load-harness traces byte-identical across repeated
+runs: no wall time ever leaks into an event.
+
+An optional ``annotate`` hook (a callable from a name to a context
+manager; the engine passes ``jax.profiler.TraceAnnotation``) wraps every
+complete span recorded while the tracer is enabled in
+``annotate("engine." + name)``, so the same span also lands on a
+profiler's host timeline, on the thread that did the work.
 
 Recording is OFF by default (``enabled=False`` → :meth:`Tracer.event` is
 a cheap early-return) and ring-buffered when on: a bounded
@@ -35,7 +40,12 @@ REQUEST_EVENTS = ("submit", "queue", "admit", "prefill_chunk",
                   "first_token", "token", "preempt", "cancel", "finish")
 
 #: engine-track phase names (complete spans, one lane each)
-PHASE_EVENTS = ("admit", "prefill", "decode", "draft", "verify", "emit")
+PHASE_EVENTS = ("admit", "prefill", "decode", "draft", "verify", "emit",
+                "decode_inputs", "decode_wait", "verify_wait", "loop_idle",
+                "freeze")
+
+#: what the ``annotate`` hook's names start with
+ANNOTATION_PREFIX = "engine."
 
 _ENGINE_PID = 0
 _REQUEST_PID = 1
@@ -68,15 +78,17 @@ class Tracer:
     """
 
     def __init__(self, clock=None, capacity: int = 65536,
-                 enabled: bool = False):
+                 enabled: bool = False, annotate=None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.clock = clock if clock is not None else time.perf_counter
         self.capacity = capacity
         self.enabled = enabled
+        self.annotate = annotate
         self._buf: deque[TraceEvent] = deque(maxlen=capacity)
         self._seen = 0            # lifetime appends (dropped = seen - len)
         self._lock = threading.Lock()
+        self._open = threading.local()     # per-thread stack of open spans
 
     @property
     def dropped(self) -> int:
@@ -98,10 +110,22 @@ class Tracer:
             self._buf.append(e)
             self._seen += 1
 
-    def span(self, name: str, *, rid: int | None = None, **args):
+    def span(self, name: str, *, rid: int | None = None, observe=None,
+             **args):
         """Context manager recording ``name`` as a complete span over the
-        enclosed block (clock-stamped entry/exit)."""
-        return _Span(self, name, rid, args)
+        enclosed block (clock-stamped entry/exit), inside the ``annotate``
+        hook while enabled.  ``observe``, if given, is called with the
+        block's duration whether or not the tracer is enabled (the block
+        is then timed either way, once); the span's ``dur`` holds it
+        after the block, and entries added to its ``args`` inside the
+        block are recorded with it."""
+        return _Span(self, name, rid, args, observe)
+
+    def current(self):
+        """The innermost span this thread has open while enabled, else
+        None."""
+        stack = getattr(self._open, "stack", None)
+        return stack[-1] if stack else None
 
     def events(self) -> list[TraceEvent]:
         with self._lock:
@@ -118,20 +142,43 @@ class Tracer:
 
 
 class _Span:
-    __slots__ = ("_tr", "_name", "_rid", "_args", "_t0")
+    __slots__ = ("_tr", "name", "rid", "args", "_observe", "_on", "_ann",
+                 "t0", "dur")
 
-    def __init__(self, tracer, name, rid, args):
-        self._tr, self._name, self._rid, self._args = tracer, name, rid, args
+    def __init__(self, tracer, name, rid, args, observe):
+        self._tr, self.name, self.rid, self.args = tracer, name, rid, args
+        self._observe = observe
+        self._ann = None
+        self.dur = None
 
     def __enter__(self):
-        self._t0 = self._tr.clock() if self._tr.enabled else 0.0
+        tr = self._tr
+        self._on = tr.enabled
+        if self._on:
+            if tr.annotate is not None:
+                self._ann = tr.annotate(ANNOTATION_PREFIX + self.name)
+                self._ann.__enter__()
+            stack = getattr(tr._open, "stack", None)
+            if stack is None:
+                stack = tr._open.stack = []
+            stack.append(self)
+        if self._on or self._observe is not None:
+            self.t0 = tr.clock()
         return self
 
     def __exit__(self, *exc):
-        if self._tr.enabled:
-            t1 = self._tr.clock()
-            self._tr.event(self._name, rid=self._rid, ts=self._t0,
-                           dur=t1 - self._t0, **self._args)
+        tr = self._tr
+        if not self._on and self._observe is None:
+            return False
+        self.dur = tr.clock() - self.t0
+        if self._observe is not None:
+            self._observe(self.dur)
+        if self._on:
+            tr._open.stack.pop()
+            tr.event(self.name, rid=self.rid, ts=self.t0, dur=self.dur,
+                     **self.args)
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
         return False
 
 

@@ -80,9 +80,10 @@ class EngineConfig:
       precision; see ``docs/quantization.md``.
     * ``trace`` / ``trace_buffer`` — request-lifecycle tracing
       (``repro.obs``): record clock-stamped span events into a ring
-      buffer of ``trace_buffer`` events, exportable as Perfetto JSON.
-      Off by default (a disabled tracer is a cheap early-return); see
-      ``docs/observability.md``.
+      buffer of ``trace_buffer`` events, exportable as Perfetto JSON,
+      each engine phase span also annotated on the JAX profiler's host
+      timeline as ``engine.<phase>``.  Off by default (a disabled tracer
+      is a cheap early-return); see ``docs/observability.md``.
     * ``spec`` / ``spec_k`` — speculative decoding: each tick drafts up
       to ``spec_k`` tokens per active request (``"ngram"`` prompt-lookup
       or ``"self_lut"`` self-speculation over nf4p LUT weights), scores

@@ -93,6 +93,7 @@ from repro.models.registry import get_model
 from repro.obs import (ITL_BUCKETS, PHASE_BUCKETS, SPEC_REQUEST_BUCKETS,
                        SPEC_WINDOW_BUCKETS, TTFT_BUCKETS, MetricsRegistry,
                        Tracer)
+from repro.obs.trace import PHASE_EVENTS
 from repro.serve.backend import make_backend
 from repro.serve.config import EngineConfig
 from repro.serve.paged import ceil_div
@@ -556,6 +557,28 @@ class EngineMetricsView:
         return self.snapshot().summary(max_batch)
 
 
+class _Program:
+    """A jitted engine program that counts the calls after which its
+    compiled cache grew (``engine_compiles_total{program}``) and marks
+    the span open around such a call ``compiled=True``."""
+    __slots__ = ("fn", "name", "_engine")
+
+    def __init__(self, engine: "Engine", name: str, impl):
+        self.fn = jax.jit(impl)
+        self.name = name
+        self._engine = engine
+
+    def __call__(self, *args):
+        n = self.fn._cache_size()
+        out = self.fn(*args)
+        if self.fn._cache_size() > n:
+            self._engine._c_compiles.add(program=self.name)
+            span = self._engine.tracer.current()
+            if span is not None:
+                span.args["compiled"] = True
+        return out
+
+
 class Engine:
     def __init__(self, cfg, params, config: EngineConfig | None = None,
                  *, clock=None):
@@ -586,12 +609,30 @@ class Engine:
         self.prefill_chunk = config.prefill_chunk
         self.backend = make_backend(self.model, cfg.family, config)
         self.caches = self.backend.caches
+        self.clock = clock if clock is not None else time.perf_counter
+        # observability: ONE registry per engine is the source of truth
+        # for every counter (self.metrics is a live view over it); the
+        # tracer shares self.clock so virtual-clock runs trace
+        # deterministically.  Both exist even when tracing is off —
+        # disabled tracer events are a cheap early-return.  While on,
+        # every span also lands on the profiler's host timeline as
+        # ``engine.<phase>``.
+        self.registry = MetricsRegistry()
+        self.metrics = EngineMetricsView(self.registry)
+        self.tracer = Tracer(clock=self.clock,
+                             capacity=config.trace_buffer,
+                             enabled=config.trace,
+                             annotate=jax.profiler.TraceAnnotation)
         # decode weights are backend-owned state: the full-precision tree
         # itself under quant=None (token-identity), a frozen 4-bit tree
         # under quant="lut4"/"int4" (affine) or "nf4"/"nf4p" (NF4 codebook
-        # + D&C residual correction) — prefill always uses self.params
-        self.decode_params = self.backend.prepare_decode_params(
-            params, config.quant)
+        # + D&C residual correction) — prefill always uses self.params.
+        # Traced, the freeze span ends once the tree is on the device.
+        with self.tracer.span("freeze", quant=config.quant or "bf16"):
+            self.decode_params = self.backend.prepare_decode_params(
+                params, config.quant)
+            if self.tracer.enabled:
+                jax.block_until_ready(self.decode_params)
         self.prefix_cache = None
         if config.prefix_cache:
             self.prefix_cache = PrefixCache(
@@ -610,7 +651,6 @@ class Engine:
         self._admitting = False        # _admit in flight (emit window)
         self._callbacks: dict[Request, list] = {}
         self._streams: dict[Request, list[queue.SimpleQueue]] = {}
-        self.clock = clock if clock is not None else time.perf_counter
         # ONE re-entrant lock guards scheduler + slot + backend state:
         # every public mutator and the whole tick run under it
         self._lock = threading.RLock()
@@ -621,22 +661,14 @@ class Engine:
         self._drain_on_stop = True
         self.scheduler = Scheduler(config.starvation_bound,
                                    clock=self.clock)
-        # observability: ONE registry per engine is the source of truth
-        # for every counter (self.metrics is a live view over it); the
-        # tracer shares self.clock so virtual-clock runs trace
-        # deterministically.  Both exist even when tracing is off —
-        # disabled tracer events are a cheap early-return.
-        self.registry = MetricsRegistry()
-        self.metrics = EngineMetricsView(self.registry)
-        self.tracer = Tracer(clock=self.clock,
-                             capacity=config.trace_buffer,
-                             enabled=config.trace)
         self._obs_init(cfg.family, config)
-        self._prefill = jax.jit(self._prefill_impl)
-        self._decode = jax.jit(self._decode_impl)
-        self._chunk_step = jax.jit(self._chunk_step_impl)
-        self._chunk_finish = jax.jit(self._chunk_finish_impl)
-        self._seed_gather = jax.jit(self.backend.gather_staging)
+        self._prefill = _Program(self, "prefill", self._prefill_impl)
+        self._decode = _Program(self, "decode", self._decode_impl)
+        self._chunk_step = _Program(self, "chunk_step", self._chunk_step_impl)
+        self._chunk_finish = _Program(self, "chunk_finish",
+                                      self._chunk_finish_impl)
+        self._seed_gather = _Program(self, "seed_gather",
+                                     self.backend.gather_staging)
         # speculative decoding: the proposer drafts, _verify scores the
         # whole (B, spec_k+1) window at the DECODE precision in one call,
         # _spec_commit re-runs a partial-accept window on recurrent
@@ -652,10 +684,11 @@ class Engine:
                     self.decode_params
                     if config.quant == SPEC_DRAFT_QUANT
                     else quantize_draft_params(params))
-                self._draft = jax.jit(self._draft_impl)
+                self._draft = _Program(self, "draft", self._draft_impl)
             self._spec = make_proposer(config.spec, self)
-            self._verify = jax.jit(self._verify_impl)
-            self._spec_commit = jax.jit(self._spec_commit_impl)
+            self._verify = _Program(self, "verify", self._verify_impl)
+            self._spec_commit = _Program(self, "spec_commit",
+                                         self._spec_commit_impl)
 
     # --- observability ---------------------------------------------------
     def _obs_init(self, family: str, config: EngineConfig):
@@ -675,6 +708,13 @@ class Engine:
             "engine_tick_phase_seconds",
             "wall seconds per engine phase per tick",
             ("phase",), buckets=PHASE_BUCKETS)
+        self._phase_observe = {
+            p: (lambda dt, p=p: self._h_phase.observe(dt, phase=p))
+            for p in PHASE_EVENTS}
+        self._c_compiles = reg.counter(
+            "engine_compiles_total",
+            "calls of a jitted engine program after which its compiled "
+            "cache grew", ("program",))
         self._h_spec_window = reg.histogram(
             "engine_spec_accepted_per_window",
             "accepted draft tokens per speculative verify window",
@@ -718,6 +758,15 @@ class Engine:
         self._g_active.set(len(self.active))
         self._g_staged.set(len(self._chunked))
         self._g_free.set(self.backend.free_capacity)
+
+    def _phase(self, name: str, **args):
+        """Time one engine phase once, as ``with self._phase(...) as p:``;
+        ``p.dur`` holds its seconds after the block.  Always observed into
+        ``engine_tick_phase_seconds{phase=name}``; while tracing, also a
+        complete span in the tracer's ring and the profiler annotation
+        ``engine.<name>`` around the block."""
+        return self.tracer.span(name, observe=self._phase_observe[name],
+                                **args)
 
     def _note_submit(self, req: Request):
         """Once-only submit accounting: the counter bumps and the trace
@@ -1243,16 +1292,13 @@ class Engine:
             slot_ids = jnp.asarray([slots[i] for i in idxs])
             tables = self.backend.admission_tables([slots[i] for i in idxs])
             rids = jnp.asarray([reqs[i].rid for i in idxs], jnp.int32)
-            t0 = self.clock()
-            nxt, self.caches = self._prefill(
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(last), slot_ids, tables, rids, self.key)
-            nxt = np.asarray(nxt)          # sync for honest wall-clock
-            dt = self.clock() - t0
-            self.metrics.prefill_s += dt
+            with self._phase("prefill", batch=k) as ph:
+                nxt, self.caches = self._prefill(
+                    self.params, jnp.asarray(toks), self.caches,
+                    jnp.asarray(last), slot_ids, tables, rids, self.key)
+                nxt = np.asarray(nxt)      # sync for honest wall-clock
+            self.metrics.prefill_s += ph.dur
             self.metrics.prefill_calls += 1
-            self._h_phase.observe(dt, phase="prefill")
-            self.tracer.event("prefill", ts=t0, dur=dt, batch=k)
             for j, i in enumerate(idxs):
                 req, slot = reqs[i], slots[i]
                 self._emit(req, int(nxt[j]))
@@ -1322,23 +1368,21 @@ class Engine:
             else remaining
         if cp.capture_at is not None and cp.consumed < cp.capture_at:
             c = min(c, cp.capture_at - cp.consumed)
-        t0 = self.clock()
         if remaining > c:
-            toks = np.asarray(req.prompt[cp.consumed:cp.consumed + c],
-                              np.int32)[None]
-            cp.staging = self._chunk_step(self.params, jnp.asarray(toks),
-                                          cp.staging, jnp.int32(cp.consumed))
-            jax.block_until_ready(cp.staging)
+            with self._phase("prefill", batch=1) as ph:
+                toks = np.asarray(req.prompt[cp.consumed:cp.consumed + c],
+                                  np.int32)[None]
+                cp.staging = self._chunk_step(
+                    self.params, jnp.asarray(toks), cp.staging,
+                    jnp.int32(cp.consumed))
+                jax.block_until_ready(cp.staging)
             cp.consumed += c
-            dt = self.clock() - t0
-            self.metrics.prefill_s += dt
+            self.metrics.prefill_s += ph.dur
             self.metrics.prefill_tokens += c
             self.metrics.prefill_calls += 1
-            self._h_phase.observe(dt, phase="prefill")
-            self.tracer.event("prefill", ts=t0, dur=dt, batch=1)
             if self.prefill_chunk is not None:
                 self.metrics.prefill_chunks += 1
-                self.tracer.event("prefill_chunk", rid=req.rid, ts=t0,
+                self.tracer.event("prefill_chunk", rid=req.rid, ts=ph.t0,
                                   consumed=cp.consumed)
             if cp.capture_at == cp.consumed:
                 cp.captured = self.backend.snapshot(cp.staging, 0)
@@ -1346,27 +1390,25 @@ class Engine:
         # final piece: pad to the bucket grid (static shapes), sample the
         # request's first token, scatter the staged row into the slab/pool
         self._chunked.pop(0)
-        pl = min(self._bucket_len(remaining),
-                 self.backend.stage_len - cp.consumed)
-        toks = np.zeros((1, pl), np.int32)
-        toks[0, :remaining] = req.prompt[cp.consumed:]
-        slot_ids = jnp.asarray([cp.slot])
-        tables = self.backend.finish_tables(cp.slot, cp.scatter_table)
-        nxt, self.caches, staged_out = self._chunk_finish(
-            self.params, jnp.asarray(toks), cp.staging,
-            jnp.int32(cp.consumed), jnp.asarray([remaining - 1]),
-            self.caches, slot_ids, tables, jnp.asarray([req.rid], jnp.int32),
-            self.key)
-        nxt = np.asarray(nxt)
-        dt = self.clock() - t0
-        self.metrics.prefill_s += dt
+        with self._phase("prefill", batch=1) as ph:
+            pl = min(self._bucket_len(remaining),
+                     self.backend.stage_len - cp.consumed)
+            toks = np.zeros((1, pl), np.int32)
+            toks[0, :remaining] = req.prompt[cp.consumed:]
+            slot_ids = jnp.asarray([cp.slot])
+            tables = self.backend.finish_tables(cp.slot, cp.scatter_table)
+            nxt, self.caches, staged_out = self._chunk_finish(
+                self.params, jnp.asarray(toks), cp.staging,
+                jnp.int32(cp.consumed), jnp.asarray([remaining - 1]),
+                self.caches, slot_ids, tables,
+                jnp.asarray([req.rid], jnp.int32), self.key)
+            nxt = np.asarray(nxt)
+        self.metrics.prefill_s += ph.dur
         self.metrics.prefill_tokens += remaining
         self.metrics.prefill_calls += 1
-        self._h_phase.observe(dt, phase="prefill")
-        self.tracer.event("prefill", ts=t0, dur=dt, batch=1)
         if self.prefill_chunk is not None:
             self.metrics.prefill_chunks += 1
-            self.tracer.event("prefill_chunk", rid=req.rid, ts=t0,
+            self.tracer.event("prefill_chunk", rid=req.rid, ts=ph.t0,
                               consumed=len(req.prompt))
         self._finish_prefix_insert(cp, staged_out)
         self._emit(req, int(nxt[0]))
@@ -1451,12 +1493,9 @@ class Engine:
         both drive exactly this body, which is what pins loop-mode output
         token-identical to sync output.  Callers MUST hold the engine
         lock."""
-        ta = self.clock()
-        self._admit_pending()
-        self._advance_chunked()
-        dta = self.clock() - ta
-        self._h_phase.observe(dta, phase="admit")
-        self.tracer.event("admit", ts=ta, dur=dta)
+        with self._phase("admit"):
+            self._admit_pending()
+            self._advance_chunked()
         if not self.active:
             self._update_gauges()
             return
@@ -1469,49 +1508,47 @@ class Engine:
         token at its own position.  Also the speculative mode's fallback
         for ticks where no slot produced a draft — spec mode degrades to
         exactly this path, never stalls."""
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        rids = np.full(self.max_batch, -1, np.int32)
-        steps = np.zeros(self.max_batch, np.int32)
-        n_active = 0
-        for s, req in enumerate(self.slots):
-            if req is not None and req.rid in self.active:
-                toks[s, 0] = req.out[-1]
-                rids[s] = req.rid
-                steps[s] = len(req.out)
-                n_active += 1
-        tables = self.backend.decode_tables([cp.slot for cp in
-                                             self._chunked])
-        t0 = self.clock()
-        nxt, self.caches = self._decode(
-            self.decode_params, jnp.asarray(toks), self.caches,
-            jnp.asarray(self.positions), tables, jnp.asarray(rids),
-            jnp.asarray(steps), self.key)
-        nxt = np.asarray(nxt)
-        dt = self.clock() - t0
-        self.metrics.decode_s += dt
+        with self._phase("decode_inputs"):
+            toks = np.zeros((self.max_batch, 1), np.int32)
+            rids = np.full(self.max_batch, -1, np.int32)
+            steps = np.zeros(self.max_batch, np.int32)
+            n_active = 0
+            for s, req in enumerate(self.slots):
+                if req is not None and req.rid in self.active:
+                    toks[s, 0] = req.out[-1]
+                    rids[s] = req.rid
+                    steps[s] = len(req.out)
+                    n_active += 1
+            tables = self.backend.decode_tables([cp.slot for cp in
+                                                 self._chunked])
+            args = (jnp.asarray(toks), self.caches,
+                    jnp.asarray(self.positions), tables, jnp.asarray(rids),
+                    jnp.asarray(steps))
+        with self._phase("decode", batch=n_active) as ph:
+            nxt, self.caches = self._decode(self.decode_params, *args,
+                                            self.key)
+            del args               # frees the old caches, as before
+            with self._phase("decode_wait"):
+                nxt = np.asarray(nxt)
+        self.metrics.decode_s += ph.dur
         self.metrics.ticks += 1
         self.metrics.occupancy_sum += n_active
         self.metrics.decode_tokens += n_active
-        self._h_phase.observe(dt, phase="decode")
-        self.tracer.event("decode", ts=t0, dur=dt, batch=n_active)
-        t2 = self.clock()
-        for s, req in enumerate(self.slots):
-            if req is None or req.rid not in self.active:
-                continue
-            self._emit(req, int(nxt[s]))
-            if req.done:
-                # an on_token callback cancelled from inside the emit:
-                # cancel() already freed the slot and active entry
-                continue
-            self.positions[s] += 1
-            if len(req.out) >= req.max_new or \
-                    self.positions[s] >= self.max_seq - 1:
-                self._retire(req)
-                self.active.pop(req.rid, None)
-                self._free_slot(s)
-        dte = self.clock() - t2
-        self._h_phase.observe(dte, phase="emit")
-        self.tracer.event("emit", ts=t2, dur=dte)
+        with self._phase("emit"):
+            for s, req in enumerate(self.slots):
+                if req is None or req.rid not in self.active:
+                    continue
+                self._emit(req, int(nxt[s]))
+                if req.done:
+                    # an on_token callback cancelled from inside the emit:
+                    # cancel() already freed the slot and active entry
+                    continue
+                self.positions[s] += 1
+                if len(req.out) >= req.max_new or \
+                        self.positions[s] >= self.max_seq - 1:
+                    self._retire(req)
+                    self.active.pop(req.rid, None)
+                    self._free_slot(s)
 
     def _spec_tick(self) -> bool:
         """One speculative advance: draft -> batched verify -> accept
@@ -1546,42 +1583,39 @@ class Engine:
                     spec_k,
                     req.max_new - len(req.out) - 1,
                     limit - 2 - int(self.positions[s])))
-        t0 = self.clock()
-        drafts = self._spec.propose(reqs, k_eff.tolist())
-        total = sum(len(d) for d in drafts)
-        dt0 = self.clock() - t0
-        self._h_phase.observe(dt0, phase="draft")
-        self.tracer.event("draft", ts=t0, dur=dt0, drafted=total)
+        with self._phase("draft") as ph:
+            drafts = self._spec.propose(reqs, k_eff.tolist())
+            total = sum(len(d) for d in drafts)
+            ph.args["drafted"] = total
         if total == 0:
             return False
         self.metrics.spec_drafted += total
-        toks = np.zeros((self.max_batch, spec_k + 1), np.int32)
-        n_valid = np.zeros(self.max_batch, np.int32)
-        n_active = 0
-        for s, req in enumerate(reqs):
-            if req is None:
-                continue
-            d = drafts[s]
-            toks[s, 0] = req.out[-1]
-            toks[s, 1:1 + len(d)] = d
-            n_valid[s] = 1 + len(d)
-            n_active += 1
-        tables = self.backend.decode_tables([cp.slot for cp in
-                                             self._chunked])
         pre = self.caches
-        t1 = self.clock()
-        tgt, post = self._verify(
-            self.decode_params, jnp.asarray(toks), pre,
-            jnp.asarray(self.positions), tables, jnp.asarray(n_valid),
-            jnp.asarray(n_valid - 1))
-        tgt = np.asarray(tgt)
-        dt1 = self.clock() - t1
-        self.metrics.decode_s += dt1
+        with self._phase("decode_inputs"):
+            toks = np.zeros((self.max_batch, spec_k + 1), np.int32)
+            n_valid = np.zeros(self.max_batch, np.int32)
+            n_active = 0
+            for s, req in enumerate(reqs):
+                if req is None:
+                    continue
+                d = drafts[s]
+                toks[s, 0] = req.out[-1]
+                toks[s, 1:1 + len(d)] = d
+                n_valid[s] = 1 + len(d)
+                n_active += 1
+            tables = self.backend.decode_tables([cp.slot for cp in
+                                                 self._chunked])
+            args = (jnp.asarray(toks), pre, jnp.asarray(self.positions),
+                    tables, jnp.asarray(n_valid))
+            last = jnp.asarray(n_valid - 1)
+        with self._phase("verify", batch=n_active) as ph:
+            tgt, post = self._verify(self.decode_params, *args, last)
+            with self._phase("verify_wait"):
+                tgt = np.asarray(tgt)
+        self.metrics.decode_s += ph.dur
         self.metrics.ticks += 1
         self.metrics.spec_ticks += 1
         self.metrics.occupancy_sum += n_active
-        self._h_phase.observe(dt1, phase="verify")
-        self.tracer.event("verify", ts=t1, dur=dt1, batch=n_active)
         accepts = np.zeros(self.max_batch, np.int32)
         partial = False
         for s, req in enumerate(reqs):
@@ -1590,54 +1624,49 @@ class Engine:
                 partial = partial or accepts[s] < len(drafts[s])
         if self.backend.needs_state and partial:
             commit_last = np.where(n_valid > 0, accepts, -1)
-            t2 = self.clock()
-            self.caches = self._spec_commit(
-                self.decode_params, jnp.asarray(toks), pre,
-                jnp.asarray(self.positions), tables,
-                jnp.asarray(n_valid),
-                jnp.asarray(commit_last.astype(np.int32)))
-            jax.block_until_ready(self.caches)
-            dt2 = self.clock() - t2
-            self.metrics.decode_s += dt2
-            self._h_phase.observe(dt2, phase="verify")
-            self.tracer.event("verify", ts=t2, dur=dt2, batch=n_active,
-                              commit=True)
+            with self._phase("verify", batch=n_active, commit=True) as ph:
+                self.caches = self._spec_commit(
+                    self.decode_params, *args,
+                    jnp.asarray(commit_last.astype(np.int32)))
+                with self._phase("verify_wait"):
+                    jax.block_until_ready(self.caches)
+            self.metrics.decode_s += ph.dur
         else:
             self.caches = post
-        t3 = self.clock()
-        emitted_total = 0
-        for s, req in enumerate(reqs):
-            if req is None or req.done or self.slots[s] is not req or \
-                    req.rid not in self.active:
-                continue   # a callback on an earlier row tore this one down
-            m = int(accepts[s])
-            rejected = len(drafts[s]) - m
-            req._spec_accepted += m
-            req._spec_rejected += rejected
-            self.metrics.spec_accepted += m
-            self.metrics.spec_rejected += rejected
-            self._h_spec_window.observe(float(m), proposer=self._spec.name)
-            for i in range(m + 1):
-                self._emit(req, int(tgt[s, i]))
-                emitted_total += 1
-                if req.done or self.slots[s] is not req:
-                    # an on_token callback cancelled/preempted this row
-                    # mid-window: the teardown already released the slot —
-                    # stop emitting and leave its bookkeeping alone
-                    break
-            else:
-                self.positions[s] += m + 1
-                if rejected:
-                    self.backend.rollback(s, rejected)
-                if len(req.out) >= req.max_new or \
-                        self.positions[s] >= self.max_seq - 1:
-                    self._retire(req)
-                    self.active.pop(req.rid, None)
-                    self._free_slot(s)
-        self.metrics.decode_tokens += emitted_total
-        dt3 = self.clock() - t3
-        self._h_phase.observe(dt3, phase="emit")
-        self.tracer.event("emit", ts=t3, dur=dt3)
+        del args
+        with self._phase("emit"):
+            emitted_total = 0
+            for s, req in enumerate(reqs):
+                if req is None or req.done or self.slots[s] is not req or \
+                        req.rid not in self.active:
+                    continue   # a callback on an earlier row tore it down
+                m = int(accepts[s])
+                rejected = len(drafts[s]) - m
+                req._spec_accepted += m
+                req._spec_rejected += rejected
+                self.metrics.spec_accepted += m
+                self.metrics.spec_rejected += rejected
+                self._h_spec_window.observe(float(m),
+                                            proposer=self._spec.name)
+                for i in range(m + 1):
+                    self._emit(req, int(tgt[s, i]))
+                    emitted_total += 1
+                    if req.done or self.slots[s] is not req:
+                        # an on_token callback cancelled/preempted this
+                        # row mid-window: the teardown already released the
+                        # slot — stop emitting and leave its bookkeeping
+                        # alone
+                        break
+                else:
+                    self.positions[s] += m + 1
+                    if rejected:
+                        self.backend.rollback(s, rejected)
+                    if len(req.out) >= req.max_new or \
+                            self.positions[s] >= self.max_seq - 1:
+                        self._retire(req)
+                        self.active.pop(req.rid, None)
+                        self._free_slot(s)
+            self.metrics.decode_tokens += emitted_total
         return True
 
     def serve(self, requests: list[Request], max_ticks: int = 512) -> dict:
@@ -1740,5 +1769,6 @@ class Engine:
                                              or not self._drain_on_stop):
                 return
             if not worked:
-                self._loop_wake.wait(backoff)
+                with self._phase("loop_idle"):
+                    self._loop_wake.wait(backoff)
                 self._loop_wake.clear()

@@ -15,8 +15,13 @@ The load-bearing pins:
   * the threaded (real background loop) drive emits a schema-valid trace
     — same completeness per request, no ordering assumptions across
     requests;
-  * the Prometheus exporter serves the text exposition over HTTP.
+  * the Prometheus exporter serves the text exposition over HTTP;
+  * one ``Engine._phase`` timing feeds histogram, ring and the profiler
+    annotation hook; the ticking thread's spans tile each tick; tokens
+    are the same with tracing on and off; ``engine_compiles_total``
+    names the program whose jit cache grew.
 """
+import contextlib
 import json
 import os
 import sys
@@ -166,6 +171,79 @@ def test_tracer_span_and_perfetto_bytes():
     assert inst["tid"] == 7 and inst["pid"] == 1
 
 
+class _Annotations:
+    """A stand-in for ``jax.profiler.TraceAnnotation`` that records what
+    it was entered with."""
+
+    def __init__(self):
+        self.entered, self.exited = [], 0
+
+    def __call__(self, name):
+        self.entered.append(name)
+
+        @contextlib.contextmanager
+        def cm():
+            yield
+            self.exited += 1
+        return cm()
+
+
+def test_annotate_entered_once_per_complete_span():
+    ann = _Annotations()
+    clk = iter(float(i) for i in range(10)).__next__
+    tr = Tracer(clock=clk, enabled=True, annotate=ann)
+    with tr.span("decode"):
+        with tr.span("decode_wait"):
+            assert tr.current().name == "decode_wait"
+        assert tr.current().name == "decode"
+    tr.event("token", rid=1)                  # instant: no annotation
+    assert tr.current() is None
+    assert ann.entered == ["engine.decode", "engine.decode_wait"]
+    assert ann.exited == 2
+    assert [e.name for e in tr.events()] == ["decode_wait", "decode",
+                                             "token"]
+
+
+def test_disabled_tracer_never_annotates_but_observes():
+    ann = _Annotations()
+    clk = iter([2.0, 2.5]).__next__
+    seen = []
+    tr = Tracer(clock=clk, enabled=False, annotate=ann)
+    with tr.span("emit", observe=seen.append) as sp:
+        assert tr.current() is None
+    assert seen == [0.5] and sp.dur == 0.5
+    with tr.span("emit") as sp:               # nothing to feed: no clock
+        pass
+    assert sp.dur is None
+    assert ann.entered == [] and tr.events() == []
+
+
+def test_phase_helper_feeds_histogram_ring_and_hook():
+    eng, _ = build_engine("yi-9b", clock=VirtualClock(), trace=True)
+    ann = _Annotations()
+    eng.tracer.annotate = ann
+    eng.clock.advance(1.0)
+    with eng._phase("decode_wait") as ph:
+        eng.clock.advance(0.125)
+        ph.args["compiled"] = True
+    assert ph.dur == 0.125
+    (ev,) = [e for e in eng.tracer.events() if e.name == "decode_wait"]
+    assert (ev.ts, ev.dur, ev.args) == (1.0, 0.125, {"compiled": True})
+    series = eng.registry.dump()["engine_tick_phase_seconds"]["series"]
+    assert series['phase="decode_wait"']["sum"] == 0.125
+    assert series['phase="decode_wait"']["count"] == 1
+    assert ann.entered == ["engine.decode_wait"] and ann.exited == 1
+    # tracing off: histogram only
+    eng.tracer.enabled = False
+    with eng._phase("decode_wait"):
+        eng.clock.advance(0.25)
+    series = eng.registry.dump()["engine_tick_phase_seconds"]["series"]
+    assert series['phase="decode_wait"']["sum"] == 0.375
+    assert len([e for e in eng.tracer.events()
+                if e.name == "decode_wait"]) == 1
+    assert ann.entered == ["engine.decode_wait"]
+
+
 # --- exporters -----------------------------------------------------------
 def test_http_exporter_and_dumps(tmp_path):
     reg = MetricsRegistry()
@@ -273,3 +351,91 @@ def test_tracing_off_records_nothing_but_metrics_live():
     # gauges settle back to idle
     assert eng.registry.gauge("engine_queue_depth").value() == 0
     assert eng.registry.gauge("engine_active_slots").value() == 0
+
+
+#: the spans that contain others on the loop thread, and what they contain
+_PARENTS = {"admit": {"prefill"}, "decode": {"decode_wait"},
+            "verify": {"verify_wait"}}
+
+
+def _loop_spans(eng):
+    return sorted(((e.ts, e.ts + e.dur, e.name) for e in eng.tracer.events()
+                   if e.rid is None and e.name != "freeze"),
+                  key=lambda s: (s[0], -s[1]))
+
+
+def test_loop_thread_spans_tile_each_tick():
+    eng, cfg = build_engine("yi-9b", trace=True, prefill_chunk=8)
+    trace = make_trace(6, 200.0, cfg.vocab_size, seed=2,
+                       deadline_budgets={0: None, 1: None},
+                       prompt_lens=(4, 12, 20))
+    rep = run_threaded(eng, trace, time_scale=0.01)
+    assert rep["finished"] == 6, rep
+    spans = _loop_spans(eng)
+    names = [n for _, _, n in spans]
+    for need in ("admit", "prefill", "decode_inputs", "decode",
+                 "decode_wait", "emit", "loop_idle"):
+        assert need in names, need
+    # spans nest or are disjoint, and only the listed parents nest
+    open_ = []
+    for a, b, n in spans:
+        while open_ and open_[-1][1] <= a:
+            open_.pop()
+        if open_:
+            pa, pb, pn = open_[-1]
+            assert b <= pb and n in _PARENTS.get(pn, ()), \
+                (pn, pa, pb, n, a, b)
+        open_.append((a, b, n))
+    # every decode tick: admit, then decode_inputs, decode containing one
+    # decode_wait, then emit
+    for i, (a, b, n) in enumerate(spans):
+        if n != "decode":
+            continue
+        prev = [s for s in spans[:i] if s[2] in ("admit", "decode_inputs",
+                                                 "emit")]
+        assert [s[2] for s in prev[-2:]] == ["admit", "decode_inputs"]
+        inner = [s for s in spans if a <= s[0] and s[1] <= b and s[2]
+                 == "decode_wait"]
+        assert len(inner) == 1
+        after = next(s for s in spans[i + 1:] if s[2] != "decode_wait")
+        assert after[2] == "emit" and after[0] >= b
+
+
+def test_tokens_identical_with_tracing_on_and_off():
+    from repro.serve.engine import Request
+    outs = []
+    for trace in (False, True):
+        eng, cfg = build_engine("yi-9b", trace=trace, prefill_chunk=8,
+                                max_batch=3)
+        reqs = [Request(rid=i, prompt=[(7 * i + j) % 50 + 1
+                                       for j in range(n)], max_new=6)
+                for i, n in enumerate((4, 12, 20, 9))]
+        assert eng.serve(reqs)["done"]
+        outs.append([r.out for r in reqs])
+        assert bool(eng.tracer.events()) == trace
+    assert outs[0] == outs[1] and all(len(o) == 6 for o in outs[0])
+
+
+def test_compile_counter_names_the_program_that_compiled():
+    from repro.serve.engine import Request
+    eng, cfg = build_engine("yi-9b", trace=True)
+    c = eng.registry.counter("engine_compiles_total", "", ("program",))
+
+    def serve(k, rid0):
+        reqs = [Request(rid=rid0 + i, prompt=[1 + i, 2, 3], max_new=2)
+                for i in range(k)]
+        eng.tracer.clear()
+        assert eng.serve(reqs)["done"]
+        return [e for e in eng.tracer.events() if e.name == "prefill"]
+
+    first = serve(1, 0)
+    assert c.value(program="prefill") == 1
+    assert c.value(program="decode") == 1
+    assert first[0].args.get("compiled") is True
+    two = serve(2, 10)                  # a new admission count
+    assert c.value(program="prefill") == 2
+    assert two[0].args == {"batch": 2, "compiled": True}
+    again = serve(2, 20)                # the same count again
+    assert c.value(program="prefill") == 2
+    assert c.value(program="decode") == 1
+    assert "compiled" not in again[0].args

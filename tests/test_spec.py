@@ -141,7 +141,8 @@ def test_spec_metrics_and_obs():
     counts = {k: s["count"] for k, s in series.items()}
     assert all(c == len(prompts) for c in counts.values()), counts
     names = {e.name for e in eng.tracer.events()}
-    assert {"draft", "verify", "emit"} <= names
+    assert {"draft", "decode_inputs", "verify", "verify_wait",
+            "emit"} <= names
 
 
 def test_spec_tokens_not_double_counted():
