@@ -17,16 +17,21 @@ kernels implement the paper's two select-tree organizations:
   applied in the epilogue.
 * :func:`lut_gemm_dc_res` — residual-corrected D&C for NON-AFFINE
   codebooks (NF4): the 6-select sum only spans separable tables, so the
-  least-squares residual of ``core.lut.dc_decompose_codebook`` is gathered
-  per code and added after the mux tree.  With the full residual the
-  reconstruction is exact up to float rounding; with a pruned residual
-  (``quant="nf4p"``) dropped codes fall through to the pure HI+LO sum and
-  the table trades capacity for a bounded accuracy cost.
+  least-squares residual of ``core.lut.dc_decompose_codebook`` is added
+  per code.  With the full residual the reconstruction is exact up to
+  float rounding; with a pruned residual (``quant="nf4p"``) dropped codes
+  fall through to the pure HI+LO sum and the table trades capacity for a
+  bounded accuracy cost.  The kernel folds HI, LO and the residual into
+  one 16-entry table on the scalar unit (bitwise the per-element values)
+  and evaluates it with one 15-select tree.  It is the TPU path of the
+  engine's ``nf4_dc`` decode matmul (``ops.nf4_dc_matmul``).
 
-Memory layout per grid step: x tile (bm, bk) bf16/f32, packed codes tile
-(bk, bn) int8, dequantized tile (bk, bn) f32 (transient), accumulator
-(bm, bn) f32 in VMEM scratch; the lookup tables whole in SMEM.
-Per-output-channel scales are applied in the epilogue on the final K step.
+Memory layout per grid step of ``lut_gemm`` and ``lut_gemm_dc``: x tile
+(bm, bk) bf16/f32, codes tile (bk, bn) int8, dequantized tile (bk, bn)
+f32 (transient), accumulator (bm, bn) f32 in VMEM scratch; the lookup
+tables whole in SMEM.  Per-output-channel scales are applied in the
+epilogue on the final K step.  ``lut_gemm_dc_res`` holds the whole K
+extent per step instead and loops over it in VMEM (see its docstring).
 """
 from __future__ import annotations
 
@@ -46,20 +51,22 @@ DEFAULT_BK = 256
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _mux_tree_dequant(codes: jax.Array, cb_ref) -> jax.Array:
+def _mux_tree_dequant(codes: jax.Array, leaves) -> jax.Array:
     """Paper's mux tree: 15 binary selects on the 4 code bits.
 
-    ``codes``: (bk, bn) int32 in [0, 16) (widened from the int8 tile: the
-    TPU vector unit has no int8 shifts); ``cb_ref``: (1, 16) codebook in
-    SMEM, read as scalar leaves.
+    ``codes``: int32 in [0, 16) (widened from the int8 tile: the TPU
+    vector unit has no int8 shifts); ``leaves``: the 16 table entries as
+    scalars (read from SMEM).  Depth first, so at most one partial result
+    per tree level is live.
     """
-    leaves = [cb_ref[0, j] for j in range(16)]   # scalar leaves
-    bits = [((codes >> b) & 1) != 0 for b in range(4)]
-    level = leaves
-    for b in range(4):                            # 8 + 4 + 2 + 1 = 15 selects
-        level = [jnp.where(bits[b], level[2 * i + 1], level[2 * i])
-                 for i in range(len(level) // 2)]
-    return level[0]
+    bits = [(codes & (1 << b)) != 0 for b in range(4)]
+
+    def tree(lo: int, b: int):                    # leaves[lo : lo + 2**(b+1)]
+        if b < 0:
+            return leaves[lo]
+        return jnp.where(bits[b], tree(lo + (1 << b), b - 1), tree(lo, b - 1))
+
+    return tree(0, 3)                             # 8 + 4 + 2 + 1 = 15 selects
 
 
 def _lut_gemm_kernel(x_ref, codes_ref, cb_ref, scale_ref, o_ref, acc_ref, *,
@@ -70,7 +77,8 @@ def _lut_gemm_kernel(x_ref, codes_ref, cb_ref, scale_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _mux_tree_dequant(codes_ref[...].astype(jnp.int32), cb_ref)
+    w = _mux_tree_dequant(codes_ref[...].astype(jnp.int32),
+                          [cb_ref[0, j] for j in range(16)])
     x = x_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -148,26 +156,27 @@ def _lut_gemm_dc_kernel(x_ref, codes_ref, hi_ref, lo_ref, zp_ref, scale_ref,
 
 
 def _lut_gemm_dc_res_kernel(x_ref, codes_ref, hi_ref, lo_ref, res_ref,
-                            zp_ref, scale_ref, o_ref, acc_ref, *, nk: int):
-    k_step = pl.program_id(2)
+                            zp_ref, scale_ref, o_ref, *, bk: int):
+    # the 6-select D&C sum and the per-code residual gather, folded into
+    # one 16-entry table on the scalar unit in the per-element order
+    # (HI[q>>2] + LO[q&3]) + RES[q]: the same values bit for bit, for one
+    # 15-select tree per element instead of 6 + 15 selects and two adds
+    table = [(hi_ref[0, q >> 2] + lo_ref[0, q & 3]) + res_ref[0, q]
+             for q in range(16)]
+    zp = zp_ref[...]                                     # (1, bn)
 
-    @pl.when(k_step == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def k_chunk(c, acc):
+        rows = pl.ds(pl.multiple_of(c * bk, bk), bk)
+        w = _mux_tree_dequant(codes_ref[rows, :].astype(jnp.int32),
+                              table) - zp                # (bk, bn) f32
+        x = x_ref[:, rows].astype(jnp.float32)
+        return acc + jax.lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    codes = codes_ref[...].astype(jnp.int32)
-    # 6-select D&C mux, then the per-code residual gather (a 16:1 select
-    # on the residual table — narrow storage in CIM, zeros where pruned)
-    w_q = (_dc_mux_dequant(codes, hi_ref, lo_ref)
-           + _mux_tree_dequant(codes, res_ref))          # (bk, bn) f32
-    w = w_q - zp_ref[...]                                # (1, bn) bcast
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(k_step == nk - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...] * scale_ref[...]       # (1, bn) bcast
+    acc = jax.lax.fori_loop(0, codes_ref.shape[0] // bk, k_chunk,
+                            jnp.zeros(o_ref.shape, jnp.float32))
+    o_ref[...] = (acc * scale_ref[...]).astype(o_ref.dtype)  # (1, bn) bcast
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -183,37 +192,54 @@ def lut_gemm_dc_res(x: jax.Array, w_codes: jax.Array, hi_tab: jax.Array,
     x: (M, K) float; w_codes: (K, N) int8; hi_tab/lo_tab: (4,) f32
     least-squares sub-tables; residual: (16,) f32 per-code correction
     (zeros at pruned codes); zero_point/scale: (N,) f32 per-output-channel.
-    Returns (M, N) f32.  The epilogue order (residual add after the
-    6-select mux, zero-point pre-MXU, scale on the final K step) is the
-    contract :func:`repro.kernels.lut_gemm.ref.lut_gemm_dc_res_ref`
-    mirrors operation-for-operation, so kernel and reference agree
-    bitwise on single-K-block shapes.
+    Returns (M, N) in ``x``'s dtype, rounded once from the f32 result.
+
+    Grid ``(cdiv(M, bm), cdiv(N, bn))``: each step holds the whole K
+    extent of its x and code blocks and dequantizes ``bk`` code rows at a
+    time in VMEM, so the codes are read from HBM once per row block.
+    Neither M nor N need divide by its block: an edge block's rows or
+    columns past the array read whatever VMEM holds and feed only output
+    rows or columns that are never written back (one output row reads
+    one x row; one output column, one code column).
+    The epilogue order (residual after the D&C sum, zero-point pre-MXU,
+    scale after the K loop) is the one
+    :func:`repro.kernels.lut_gemm.ref.lut_gemm_dc_res_ref` follows; the
+    two differ only in how the dot sums (see ``docs/kernels.md``).
     """
     m, k = x.shape
     k2, n = w_codes.shape
     assert k == k2 and hi_tab.shape == (4,) and lo_tab.shape == (4,)
     assert residual.shape == (16,)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
-    nk = k // bk
+    assert k % bk == 0, (k, bk)
 
     return pl.pallas_call(
-        functools.partial(_lut_gemm_dc_res_kernel, nk=nk),
-        grid=(m // bm, n // bn, nk),
+        functools.partial(_lut_gemm_dc_res_kernel, bk=bk),
+        grid=(pl.cdiv(m, bm), pl.cdiv(n, bn)),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
             _SMEM,
             _SMEM,
             _SMEM,
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_bytes(bm, k, bn, bk)),
+        name="lut_gemm_dc_res",
         interpret=interpret,
     )(x, w_codes, hi_tab.reshape(1, 4), lo_tab.reshape(1, 4),
       residual.reshape(1, 16), zero_point.reshape(1, n), scale.reshape(1, n))
+
+
+def _vmem_bytes(bm: int, k: int, bn: int, bk: int) -> int:
+    """Scoped VMEM for one :func:`lut_gemm_dc_res` step: double-buffered
+    x (f32 at most), code and output blocks, room for a few (bk, bn) f32
+    transients of the select tree, and 8 MiB spare."""
+    blocks = 2 * (bm * k * 4 + k * bn + bm * bn * 4)
+    return blocks + 8 * bk * bn * 4 + 8 * 2 ** 20
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))

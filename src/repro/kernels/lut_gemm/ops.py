@@ -1,27 +1,33 @@
 """Public wrappers: codebook quantize + LUT GEMM (weight-only 4-bit).
 
-Four entry points over the LUT kernels:
+The entry points over the LUT kernels:
 
+* :func:`quantized_matmul` — the serving decode hot path: a frozen
+  :class:`~repro.core.quant.QuantizedWeight`, dispatched on the
+  container's static ``kernel`` tag.  ``"lut_dc"`` reconstructs the
+  weight by summing the two D&C sub-table selects through
+  ``core.lut.mux_tree_select`` (3 + 3 muxes — the paper's area
+  argument); ``"dequant"`` is the conventional-math baseline
+  ``(q - z_w) * s_w`` (both reconstruct the identical affine grid, so
+  engine tokens match bit-for-bit between ``quant="lut4"`` and
+  ``"int4"``); ``"nf4_dc"`` adds the per-code residual to the D&C sum
+  (non-affine NF4, exact up to float rounding with the full residual,
+  bounded-error with a pruned one); ``"nf4_dequant"`` is the direct
+  full-table NF4 lookup the residual path is pinned against.  All four
+  run as ``jnp`` primitives, which rebuild the weight in f32 — except
+  ``"nf4_dc"`` lowered for the TPU, which runs :func:`nf4_dc_matmul`.
+  The ``jnp`` branch is the path on every other platform and the
+  kernel's reference.
+* :func:`nf4_dc_matmul` — an ``nf4_dc`` weight through the fused
+  ``lut_gemm_dc_res`` Pallas kernel: codes dequantized in VMEM, tiles
+  chosen from the shapes.
 * :func:`nf4_matmul_kernel` — NF4 codebook weights through the full-table
   Pallas kernel (paper Fig 1 select tree, programmable codebook).
 * :func:`lut4_matmul_kernel` — uniform-int4 weights through the D&C
   sub-table Pallas kernel (paper Figs 2/3: two 4-entry tables, 6 selects).
-* :func:`nf4dc_matmul_kernel` — NF4 weights through the residual-corrected
-  D&C Pallas kernel (6-select mux + per-code residual epilogue — the
-  non-affine extension; a prune threshold reproduces ``quant="nf4p"``).
-* :func:`quantized_matmul` — the serving decode hot path: a frozen
-  :class:`~repro.core.quant.QuantizedWeight` evaluated with jnp primitives
-  (jit-compatible on every backend; the Pallas kernels above implement the
-  same math for TPU).  Dispatches on the container's static ``kernel`` tag:
-  ``"lut_dc"`` reconstructs the weight by summing the two D&C sub-table
-  selects through ``core.lut.mux_tree_select`` (3 + 3 muxes — the paper's
-  area argument); ``"dequant"`` is the conventional-math baseline
-  ``(q - z_w) * s_w`` (both reconstruct the identical affine grid, so
-  engine tokens match bit-for-bit between ``quant="lut4"`` and ``"int4"``);
-  ``"nf4_dc"`` adds the per-code residual gather to the D&C sum (non-affine
-  NF4, exact up to float rounding with the full residual, bounded-error
-  with a pruned one); ``"nf4_dequant"`` is the direct full-table NF4
-  lookup the residual path is pinned against.
+* :func:`nf4dc_matmul_kernel` — quantizes a float weight to ``nf4_dc``
+  and runs :func:`nf4_dc_matmul` (a prune threshold reproduces
+  ``quant="nf4p"``).
 """
 from __future__ import annotations
 
@@ -57,10 +63,24 @@ def quantized_matmul(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     least-squares correction of ``core.lut.dc_decompose_codebook``, zeroed
     at pruned codes under ``quant="nf4p"``) or as the conventional
     full-table lookup (``nf4_dequant``, the 15-select oracle).
+
+    Lowered for the TPU, ``nf4_dc`` runs the fused Pallas kernel
+    (:func:`nf4_dc_matmul`), which dequantizes code tiles in VMEM; on
+    every other platform, where that kernel cannot lower, it takes the
+    ``jnp`` path, which is also its reference.
     """
     assert qw.codes.ndim == 2, (
         f"quantized_matmul expects a sliced 2-D weight, got "
         f"{qw.codes.shape}; scan-stacked leaves are sliced by lax.scan")
+    if qw.kernel == "nf4_dc":
+        return jax.lax.platform_dependent(x, qw, tpu=nf4_dc_matmul,
+                                          default=_jnp_matmul)
+    return _jnp_matmul(x, qw)
+
+
+def _jnp_matmul(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
+    """:func:`quantized_matmul` in ``jnp``: rebuild the weight in f32
+    through ``core.lut.mux_tree_select``, then one f32 matmul."""
     q = qw.codes.astype(jnp.int32)
     if qw.kernel == "lut_dc":
         w_q = (codebook_dequant(q >> 2, qw.hi_tab)
@@ -76,6 +96,41 @@ def quantized_matmul(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     else:                                   # "dequant": conventional math
         w = dequantize(q, qw.qparams)
     return (x.astype(jnp.float32) @ w).astype(x.dtype)
+
+
+#: code bytes in one block of :func:`nf4_dc_matmul`'s grid: large enough
+#: that a decode projection takes a few grid steps (each costs about
+#: 0.35 us), small enough that the block's DMA, exposed before the first
+#: step, stays a few microseconds
+DC_RES_BLOCK_BYTES = 2 * 2 ** 20
+#: code rows dequantized per loop iteration inside a block
+DC_RES_BK = 256
+
+
+def nf4_dc_matmul(x: jax.Array, qw: QuantizedWeight, *,
+                  interpret: bool = False) -> jax.Array:
+    """``x @ dequant(qw)`` for an ``nf4_dc`` weight through
+    :func:`lut_gemm_dc_res`: the codes are read once, dequantized in VMEM,
+    and no float weight is written to HBM.
+
+    ``x``: (..., K) float, flattened to (M, K); ``qw.codes``: (K, N).
+    Tiles come from the shapes: every row in one row block (M rounded up
+    to 16, at most 256), the whole K extent per block, and ``bn`` columns
+    so that a block holds about :data:`DC_RES_BLOCK_BYTES` of codes.
+    Ragged edge blocks are masked, so the wrapper pads nothing, and the
+    result comes out in ``x``'s dtype.
+    """
+    k, n = qw.codes.shape
+    x2 = x.reshape(-1, k)
+    m = x2.shape[0]
+    bm = min(-(-m // 16) * 16, 256)
+    cap = max(128, DC_RES_BLOCK_BYTES // k // 128 * 128)
+    bn = n if n <= cap else cap
+    bk = DC_RES_BK if k % DC_RES_BK == 0 else k
+    out = lut_gemm_dc_res(x2, qw.codes, qw.hi_tab, qw.lo_tab, qw.residual,
+                          qw.zero_point, qw.scale, bm=bm, bn=bn, bk=bk,
+                          interpret=interpret)
+    return out.reshape(*x.shape[:-1], n)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -126,20 +181,10 @@ def nf4dc_matmul_kernel(x: jax.Array, w: jax.Array,
     Quantizes ``w`` with :func:`~repro.core.quant.quantize_weight` in
     ``nf4_dc`` mode (the same transform ``EngineConfig(quant="nf4")``
     freezes at engine construction; a ``prune_threshold`` reproduces
-    ``"nf4p"``) and evaluates through :func:`lut_gemm_dc_res`.  Pads every
-    dim to the fitted block.
+    ``"nf4p"``) and evaluates through :func:`nf4_dc_matmul`.
     """
     qw = quantize_weight(w, kernel="nf4_dc", prune_threshold=prune_threshold)
-    m, k = x.shape
-    n = w.shape[1]
-    bm, bn, bk = gemm_blocks(m, k, n)
-    xp = jnp.pad(x, [(0, (-m) % bm), (0, (-k) % bk)])
-    cp = jnp.pad(qw.codes, [(0, (-k) % bk), (0, (-n) % bn)])
-    zp = jnp.pad(qw.zero_point, [(0, (-n) % bn)])
-    sp = jnp.pad(qw.scale, [(0, (-n) % bn)])
-    out = lut_gemm_dc_res(xp, cp, qw.hi_tab, qw.lo_tab, qw.residual, zp, sp,
-                          bm=bm, bn=bn, bk=bk, interpret=interpret)
-    return out[:m, :n]
+    return nf4_dc_matmul(x, qw, interpret=interpret)
 
 
 def gemm_blocks(m: int, k: int, n: int) -> tuple[int, int, int]:

@@ -14,9 +14,10 @@ Two reference semantics, matching the two Pallas kernels:
 * :func:`lut_gemm_dc_res_ref` — residual-corrected D&C (non-affine NF4):
   the 6-select sum plus a per-code residual gather.  Unlike the affine
   refs (which fold the scale into the weight before the matmul — the
-  order ``ops.quantized_matmul`` uses), this one mirrors the Pallas
-  kernel's epilogue order exactly (zero-point pre-matmul, scale after),
-  so kernel and reference are BITWISE-identical on single-K-block shapes.
+  order of ``ops.quantized_matmul``'s jnp path), this one follows the
+  Pallas kernel's epilogue order (zero-point pre-matmul, scale after), so
+  kernel and reference build the same weights bit for bit and differ only
+  in the dot's summation order: they agree to f32 rounding.
 """
 from __future__ import annotations
 
@@ -55,8 +56,10 @@ def lut_gemm_dc_res_ref(x: jax.Array, w_codes: jax.Array, hi_tab: jax.Array,
     ``w_codes``: (K, N) int8 codes in [0, 16); ``hi_tab``/``lo_tab``: (4,)
     least-squares sub-tables; ``residual``: (16,) per-code correction
     (zeros at pruned codes); ``zero_point``/``scale``: (N,) per-channel.
-    Operation order mirrors ``lut_gemm.lut_gemm_dc_res`` exactly (see its
-    docstring) — the bitwise-parity contract.  Returns (M, N) f32.
+    The weights and the epilogue order are those of
+    ``lut_gemm.lut_gemm_dc_res`` (see its docstring); the kernel's blocked
+    dot sums in another order, so the two agree to f32 rounding.
+    Returns (M, N) f32.
     """
     q = w_codes.astype(jnp.int32)
     w_q = (hi_tab[q >> 2] + lo_tab[q & 3]) + residual[q]

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.quant import QuantizedWeight
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.lut_gemm import ops as lut_ops
 from repro.kernels.lut_gemm.lut_gemm import lut_gemm_dc, lut_gemm_dc_res
@@ -27,6 +28,11 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 #: shared attention 32 heads of d=64
 LUT_SHAPES = [(2048, 8384), (2048, 8192)]
 DECODE_M = 8
+#: zamba2-1.2b's decode projections (K, N): Mamba2 in and out, shared
+#: attention, shared MLP up and down; served 12 rows at a time
+NF4_DECODE_SHAPES = [(2048, 8384), (4096, 2048), (2048, 2048), (2048, 8192),
+                     (8192, 2048)]
+NF4_DECODE_ROWS = 12
 SSD_HEADS, SSD_P, SSD_N, SSD_CHUNK, SSD_SEQ = 64, 64, 64, 256, 512
 ATTN_HEADS, ATTN_D, ATTN_SEQ = 32, 64, 1024
 
@@ -84,6 +90,21 @@ def test_lut_gemm_compiles_for_v5e(sds, kernel, k, n):
                  x, codes, tab4, tab4, sds((16,), f32), chan, chan)
 
 
+@pytest.mark.parametrize("k,n", NF4_DECODE_SHAPES)
+def test_nf4_decode_matmul_takes_fused_kernel_on_v5e(sds, k, n):
+    """Lowered for the TPU, an ``nf4_dc`` decode matmul runs the Pallas
+    LUT kernel and holds no float copy of the weight: its temporaries
+    stay below one f32 (K, N) weight (the ``jnp`` path's mux tree needs
+    about twenty)."""
+    f32 = jnp.float32
+    chan, tab4 = sds((n,), f32), sds((4,), f32)
+    qw = QuantizedWeight(sds((k, n), jnp.int8), chan, chan, tab4, tab4,
+                         sds((16,), f32), kernel="nf4_dc")
+    x = sds((NF4_DECODE_ROWS, 1, k), jnp.bfloat16)
+    compiled = _compile(lut_ops.quantized_matmul, x, qw)
+    assert compiled.memory_analysis().temp_size_in_bytes < k * n * 4
+
+
 def test_ssd_scan_compiles_for_v5e(sds):
     """The resumable, masked SSD chunk scan at one sequence's heads."""
     bh, f32 = SSD_HEADS, jnp.float32
@@ -106,7 +127,8 @@ def test_flash_attention_compiles_for_v5e(sds):
 
 @pytest.mark.parametrize("fn", [
     lut_ops.nf4_matmul_kernel, lut_ops.lut4_matmul_kernel,
-    lut_ops.nf4dc_matmul_kernel, ssd_ops.ssd_chunked_kernel,
+    lut_ops.nf4dc_matmul_kernel, lut_ops.nf4_dc_matmul,
+    ssd_ops.ssd_chunked_kernel,
     lut_gemm_dc, lut_gemm_dc_res, ssd_scan, flash_attention,
 ], ids=lambda f: f.__name__)
 def test_kernels_do_not_default_to_interpret(fn):
