@@ -11,6 +11,12 @@ A traffic file holds:
 * ``loop``: ``"open"`` (requests sent on a schedule, whether or not
   earlier ones finished) or ``"closed"`` (``clients`` callers, each
   sending its next request when the last one completed);
+* closed loop: ``rounds``, the length of its cycle.  The clients send
+  round after round of ``clients`` requests, and every ``rounds`` rounds
+  hold the same multiset of ``rounds * clients`` sizes, each round one
+  size of each of ``clients`` strata of it (:func:`stratified_rounds`).
+  A window reaches only its first few rounds, so the cycle is kept to
+  about what it reaches: every window is then nearly the same work;
 * ``prompt`` / ``output``: token-length distributions, ``{"median",
   "sigma", "min", "max"}`` of a lognormal, clipped;
 * open loop: ``rate_rps`` and ``phases``, a repeated cycle of
@@ -21,15 +27,12 @@ A traffic file holds:
 """
 from __future__ import annotations
 
+import itertools
 import math
 from dataclasses import dataclass
 from statistics import NormalDist
 
 import numpy as np
-
-#: sizes of a closed loop's pool; clients draw from it in turn
-CLOSED_POOL = 4096
-
 
 @dataclass(frozen=True)
 class Item:
@@ -99,11 +102,29 @@ def open_schedule(traffic: dict, seconds: float, seed: int,
             for i, (d, p, o) in enumerate(zip(dues, prompts, outputs))]
 
 
+def stratified_rounds(spec: dict, clients: int, rounds: int,
+                      rng: np.random.Generator) -> np.ndarray:
+    """One cycle: ``rounds * clients`` lengths, round after round.  The
+    multiset of :func:`length_multiset` is cut into ``clients`` strata of
+    ``rounds`` neighbouring quantiles, and each round takes one length of
+    every stratum; the seed picks which member of a stratum a round takes
+    and which client of the round gets it."""
+    strata = length_multiset(spec, clients * rounds).reshape(clients, rounds)
+    strata = rng.permuted(strata, axis=1)
+    return rng.permuted(strata, axis=0).T.reshape(-1)
+
+
 def client_items(traffic: dict, seed: int, vocab: int, c: int):
-    """Closed loop: client ``c``'s requests, in order.  Client ``c`` takes
-    items ``c``, ``c + clients``, ... of one pool of ``CLOSED_POOL``
-    sizes, so the clients together walk the pool from its start."""
-    clients = traffic["clients"]
-    prompts, outputs = _sizes(traffic, CLOSED_POOL, seed)
-    for i in range(c, CLOSED_POOL, clients):
-        yield _item(i, 0.0, prompts[i], outputs[i], seed, vocab)
+    """Closed loop: client ``c``'s requests, in order, without end.
+    Client ``c`` takes items ``c``, ``c + clients``, ... of the cycles of
+    stratified rounds, so the clients together walk them a round at a
+    time."""
+    clients, rounds = traffic["clients"], traffic["rounds"]
+    n = clients * rounds
+    rp, ro = rng_for(seed, 2), rng_for(seed, 3)
+    for k in itertools.count():
+        prompts = stratified_rounds(traffic["prompt"], clients, rounds, rp)
+        outputs = stratified_rounds(traffic["output"], clients, rounds, ro)
+        for i in range(c, n, clients):
+            yield _item(k * n + i, 0.0, prompts[i], outputs[i], seed,
+                        vocab)

@@ -1,8 +1,8 @@
 """The decode program's share of its roofline: the least time the chip
-needs for the work of the active rows (``bench/work.py``, from the
-configuration's shapes; frozen projections at 4 bits) over the decode
-program's device time.  ``ctx.decode_bound`` says whether memory or
-compute bounds it."""
+needs for the work of the active rows (counted from the configuration's
+shapes by the ``bench/work/<work>.py`` it names; frozen projections at
+4 bits) over the decode program's device time.  ``ctx.decode_bound``
+says whether memory or compute bounds it."""
 LAYER = "decode kernels"
 UNIT = "%"
 MOVES = "itl_p95_ms"

@@ -1,4 +1,5 @@
-"""Plain float32 reference forward passes of the served models.
+"""Plain float32 reference forward passes of a Mamba2 model (family
+``ssm``) and of a Zamba2 hybrid (family ``hybrid``).
 
 Straightforward ``jax.numpy`` at ``jax.default_matmul_precision("highest")``:
 no cache, no batching, no kernels, one sequence at a time, one layer per

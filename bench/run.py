@@ -7,10 +7,16 @@ One process holds the chip: it makes the weights from the seed on the
 device, builds the engine (``repro.serve.engine.Engine``), warms every
 program shape the cell's traffic uses, then drives the engine's
 background loop for ``--seconds`` with the cell's traffic from client
-threads.  The last line of standard output is one JSON object:
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
-``--trace 1`` also ``breakdown``, and last ``checks``, each number
-compared beside its limit (also the last lines of standard error).
+threads.  Nothing here knows a model family: the configuration file
+names the module of its plain reference (``bench/reference/<name>.py``,
+``Reference``) and of its decode step's work count
+(``bench/work/<name>.py``, ``decode_step``), and ``bench/cell.py`` finds
+both under the checkout and hands them over on the ``Cell``.
+
+The last line of standard output is one JSON object: ``correct``,
+``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
+also ``breakdown``, and last ``checks``, each number compared beside its
+limit (also the last lines of standard error).
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics, read from a profiler trace of the first
@@ -22,8 +28,9 @@ over the same sample, and so comes out false.  The program's own widest
 gap is printed beside it.  The benchmark's own runs do not pass it.
 
 Exits 3 with no result when JAX finds no TPU or fewer chips than the
-cell asks for, and 2 when the cell, its files or the ``repro`` package
-beside ``bench/`` cannot be found.
+cell asks for, and 2 when the cell, its files, the modules its
+configuration names or the ``repro`` package beside ``bench/`` cannot be
+found.
 """
 from __future__ import annotations
 
@@ -48,7 +55,7 @@ if str(BENCH) not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from cell import CellError, load_cell  # noqa: E402
+from cell import CellError, load_cell, load_module  # noqa: E402
 from e2e import itl_values, p95, tokens_in_window, ttft_values  # noqa: E402
 
 #: the persistent compilation cache: a fixed path inside the checkout
@@ -181,15 +188,10 @@ class TracedContext:
 
 
 def load_reader(name: str):
-    import importlib.util
     path = BENCH / "metrics" / f"{name}.py"
     if not path.is_file():
         raise CellError(f"no reader {path} for per-layer metric {name!r}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_module(path)
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool,
@@ -209,7 +211,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     import load
     import weights
     from peaks import peaks_for
-    from reference.model import Reference
 
     devs = require_devices(cell.chips) if check_device else jax.devices()
     notes = []
@@ -339,8 +340,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     dims = reference_dims(cell.config, cfg)
     t_ref = time.perf_counter()
-    ref = Reference(dims, params, seq_len=max_seq,
-                    decode_nf4=cell.config.get("decode_nf4", ()))
+    ref = cell.Reference(dims, params, seq_len=max_seq,
+                         decode_nf4=cell.config.get("decode_nf4", ()))
     gaps = check.served_gaps(ref, chosen)
     # no served token to check reads 0 here and fails served_tokens_checked
     widest = float(gaps.max()) if gaps.size else 0.0
@@ -348,9 +349,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                  f"tokens, {time.perf_counter() - t_ref:.1f} s")
     if control:
         # the control in the program's place: its widest gap is compared
-        low = Reference(dims, params, seq_len=max_seq,
-                        decode_nf4=cell.config.get("decode_nf4", ()),
-                        low_precision=True)
+        low = cell.Reference(dims, params, seq_len=max_seq,
+                             decode_nf4=cell.config.get("decode_nf4", ()),
+                             low_precision=True)
         cg = check.control_gaps(ref, low, chosen)
         notes.append(f"program's widest gap (not compared in a control "
                      f"run): {widest:.6f}")
@@ -378,7 +379,6 @@ def traced_metrics(cell, state, engine, recs, cfg, peaks, serving):
     from jax.profiler import ProfileData
 
     import trace_reduce
-    import work
 
     path = glob.glob(os.path.join(state["trace_dir"], "**", "*.xplane.pb"),
                      recursive=True)
@@ -404,8 +404,9 @@ def traced_metrics(cell, state, engine, recs, cfg, peaks, serving):
     step_work = bound = None
     if steps and rows:
         frozen = frozenset(cell.config.get("decode_nf4", ()))
-        step_work = work.decode_step(cell.config["model"], cfg.family,
-                                     rows / steps, keys / steps, frozen)
+        step_work = cell.decode_step(
+            cell.config["model"], cfg.family, rows / steps, keys / steps,
+            frozen)
         bound = step_work.least_s(peaks)[1]
     ctx = TracedContext(trace=red, engine=delta,
                         max_batch=serving["max_batch"], span=(t0, t1),

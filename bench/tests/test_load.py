@@ -3,6 +3,7 @@ work in another order."""
 from __future__ import annotations
 
 import json
+import itertools
 from collections import Counter
 
 import load
@@ -53,3 +54,32 @@ def test_closed_clients_repeat_for_a_seed():
                 for c in range(BATCH["clients"])]
     assert first(BIG) == first(BIG)
     assert first(BIG) != first(5)
+
+
+def _rounds(seed, cycles):
+    """The first ``cycles`` cycles of the closed loop, round by round."""
+    n, r = BATCH["clients"], BATCH["rounds"]
+    items = [list(itertools.islice(load.client_items(BATCH, seed, 32000, c),
+                                   cycles * r)) for c in range(n)]
+    return [[items[c][k] for c in range(n)] for k in range(cycles * r)]
+
+
+def test_closed_cycles_hold_the_same_sizes():
+    """Every cycle of ``rounds`` rounds is the same multiset of sizes for
+    every seed, in another order; each round takes one size of every
+    stratum, so a part of a cycle is nearly the same work too."""
+    n, r = BATCH["clients"], BATCH["rounds"]
+    a, b = _rounds(1, 3), _rounds(BIG, 3)
+    assert a[0] != b[0]
+    for key, spec in ((lambda i: len(i.prompt), BATCH["prompt"]),
+                      (lambda i: i.max_new, BATCH["output"])):
+        whole = Counter(load.length_multiset(spec, n * r).tolist())
+        for s in (a, b):
+            for k in range(0, len(s), r):
+                assert Counter(key(i) for rd in s[k:k + r]
+                               for i in rd) == whole
+        strata = load.length_multiset(spec, n * r).reshape(n, r)
+        for rd in a + b:
+            got = sorted(key(i) for i in rd)
+            assert all(lo <= v <= hi for v, lo, hi in
+                       zip(got, strata[:, 0], strata[:, -1]))

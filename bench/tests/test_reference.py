@@ -12,7 +12,7 @@ import tiny
 import check
 import weights
 from e2e import Record
-from reference.model import Reference
+from reference.mamba2_hybrid import Reference
 from run import model_config, reference_dims
 
 #: bf16 program against the f32 reference at these widths reads about

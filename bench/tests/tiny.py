@@ -4,10 +4,11 @@ from __future__ import annotations
 
 import copy
 
-from cell import Cell
+from cell import Cell, named_objects
 
 MAMBA2 = {
     "name": "mamba2-tiny", "arch": "mamba2-1.3b",
+    "reference": "mamba2_hybrid", "work": "mamba2_hybrid",
     "model": {"num_layers": 2, "d_model": 64, "vocab_size": 256,
               "norm_eps": 1e-5, "dtype": "bfloat16",
               "ssm": {"state_dim": 16, "expand": 2, "head_dim": 16,
@@ -20,6 +21,7 @@ MAMBA2 = {
 
 ZAMBA2_NF4 = {
     "name": "zamba2-nf4-tiny", "arch": "zamba2-1.2b",
+    "reference": "mamba2_hybrid", "work": "mamba2_hybrid",
     "model": {"num_layers": 4, "d_model": 64, "num_heads": 4,
               "num_kv_heads": 4, "d_ff": 128, "vocab_size": 256,
               "head_dim": 16, "rope_theta": 10000.0, "norm_eps": 1e-5,
@@ -44,7 +46,7 @@ OPEN = {"loop": "open", "rate_rps": 12.0,
         "output": {"median": 8, "sigma": 0.6, "min": 2, "max": 40},
         "drain_seconds": 60}
 
-CLOSED = {"loop": "closed", "clients": 4,
+CLOSED = {"loop": "closed", "clients": 4, "rounds": 4,
           "prompt": {"median": 24, "sigma": 0.5, "min": 4, "max": 80},
           "output": {"median": 10, "sigma": 0.5, "min": 2, "max": 40}}
 
@@ -59,4 +61,4 @@ def cell(config: dict, traffic: dict, **check) -> Cell:
         e2e = e2e[1:]
     return Cell(name=config["name"], chips=1, config=config,
                 traffic=copy.deepcopy(traffic), end_to_end=tuple(e2e),
-                per_layer=())
+                per_layer=(), **named_objects(config))

@@ -14,6 +14,10 @@ Leaves are drawn by name:
 * ``conv_w``: 0.2 N(0, 1); ``conv_b``: 0.05 N(0, 1);
 * ``embed``: 0.02 N(0, 1);
 * every other leaf is a matrix ``(..., in, out)``: N(0, 1) / sqrt(in).
+  Leading axes are stacks: layers, and the experts of a mixture, so a
+  router ``(layers, d, experts)`` and the stacked experts ``(layers,
+  experts, in, out)`` are scaled by their fan-in ``shape[-2]``
+  (``bench/tests/test_weights.py``).
 """
 from __future__ import annotations
 

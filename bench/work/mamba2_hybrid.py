@@ -1,47 +1,17 @@
-"""The operations and bytes a decode step needs, from a configuration's
-shapes alone (not from the program, its HLO or its storage).
+"""The decode step of a Mamba2 model (family ``ssm``) and of a Zamba2
+hybrid (family ``hybrid``), counted from the configuration's shapes.
 
 A decode step of ``rows`` active rows reads every weight once, reads and
 writes each row's recurrent state, and, for the shared attention of a
 hybrid, reads each row's keys and values up to its position and writes
-one more.  A projection frozen to NF4 counts 4 bits a weight plus its
-float32 column scale and its code tables (16 + 4 + 4 float32); every
-other weight counts at its stored width (bf16 matrices, float32 gains
-and SSM scalars).  FLOPs count the matrix products, the SSM state update
-and read-out, the depthwise convolution and attention scores and values;
-elementwise work is left out.
+one more.  Beyond the matrix products, FLOPs count the SSM state update
+and read-out, the depthwise convolution and attention scores and values.
 """
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
 
-BF16, F32 = 2, 4
-NF4_TABLE_BYTES = (16 + 4 + 4) * F32
-
-
-@dataclass(frozen=True)
-class Work:
-    flops: float
-    bytes: float
-
-    def __add__(self, o):
-        return Work(self.flops + o.flops, self.bytes + o.bytes)
-
-    def scale(self, k: float) -> "Work":
-        return Work(self.flops * k, self.bytes * k)
-
-    def least_s(self, peaks) -> tuple[float, str]:
-        """The least time on a chip with ``peaks``, and what bounds it."""
-        tc = self.flops / peaks.flops_bf16
-        tm = self.bytes / peaks.hbm_bytes_s
-        return (tc, "compute") if tc >= tm else (tm, "memory")
-
-
-def matrix_bytes(k: int, n: int, frozen: bool, bits: int = 4) -> float:
-    if frozen:
-        return k * n * bits / 8 + n * F32 + NF4_TABLE_BYTES
-    return k * n * BF16
+from work import BF16, F32, Work, head, matrix_bytes
 
 
 def mamba2_dims(model: dict) -> dict:
@@ -88,12 +58,6 @@ def shared_block(model: dict, rows: int, keys: int,
     proj = sum(2 * k * n for k, n in mats.values())
     kv_rw = (keys + rows) * nkv * dh * 2 * BF16   # read all, write one
     return Work(rows * proj + 4 * nq * dh * keys, w + kv_rw)
-
-
-def head(model: dict, rows: int) -> Work:
-    """Embedding gather, final norm and the (unfrozen) output head."""
-    d, v = model["d_model"], model["vocab_size"]
-    return Work(rows * 2 * d * v, d * v * BF16 + rows * d * BF16 + d * F32)
 
 
 def decode_step(model: dict, family: str, rows: float, keys: float,
