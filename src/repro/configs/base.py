@@ -8,7 +8,13 @@ from repro.core.layers import QuantConfig
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int            # routed experts
+    """Routed experts, of which this device holds ``num_experts``.
+
+    The router scores ``router_width`` experts (0: as many as are held);
+    the experts held here are ``first_held .. first_held + num_experts -
+    1`` of them, so one device's share of an expert-parallel deployment
+    is the same model with a narrower ``num_experts``."""
+    num_experts: int            # routed experts held here
     num_shared: int = 0
     top_k: int = 2
     d_expert: int = 0           # expert FFN hidden size
@@ -16,6 +22,13 @@ class MoEConfig:
     first_dense: int = 1        # leading dense layers (deepseek-v2 style)
     dense_ff: int = 0           # FFN width of the dense layers
     aux_loss_coef: float = 0.001
+    router_width: int = 0       # experts the router scores; 0 = num_experts
+    first_held: int = 0         # router index of the first held expert
+
+    @property
+    def routed(self) -> int:
+        """Experts the router scores."""
+        return self.router_width or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -25,6 +38,22 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_dim: int = 128
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (arXiv:2309.00071), keyed as DeepSeek-V2's
+    ``config.json`` keys it: frequencies between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position_embeddings`` are ramped from unscaled to
+    divided by ``factor``; the softmax scale gains
+    ``yarn_get_mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
 
 
 @dataclass(frozen=True)
@@ -71,6 +100,7 @@ class ModelConfig:
     head_dim: int = 0            # 0 -> d_model // num_heads
     mlp_type: str = "swiglu"     # swiglu | gelu
     rope_theta: float = 10000.0
+    rope_scaling: RopeScaling | None = None
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
     tie_embeddings: bool = False
@@ -131,7 +161,8 @@ class ModelConfig:
         )
         if self.moe:
             small["moe"] = replace(self.moe, num_experts=8, top_k=2,
-                                   d_expert=64, dense_ff=256)
+                                   d_expert=64, dense_ff=256,
+                                   router_width=0, first_held=0)
         if self.mla:
             small["mla"] = MLAConfig(kv_lora_rank=32, q_lora_rank=0,
                                      qk_nope_dim=16, qk_rope_dim=16, v_dim=16)

@@ -266,20 +266,23 @@ def quantize_weight(w: jax.Array, kernel: str = "lut_dc",
 
 
 #: decode-projection leaf names eligible for engine-level quantization.
-#: Everything here is consumed through ``core.layers.quant_matmul``; leaves
-#: used directly (MLA's w_uk/w_uv reshapes, MoE routed-expert einsums,
-#: routers, norms, embeddings, lm_head) are deliberately absent.
+#: Everything here is consumed through ``core.layers.quant_matmul`` or,
+#: for the stacked routed experts of an MoE layer, through
+#: ``kernels.lut_gemm.ops.quantized_expert_matmul``; leaves used directly
+#: (MLA's w_uk/w_uv reshapes, routers, norms, embeddings, lm_head) are
+#: deliberately absent.
 DECODE_QUANT_TARGETS = frozenset({
     "wq", "wk", "wv", "wo", "w_dq", "w_uq", "w_dkv",      # attention
-    "w_up", "w_gate", "w_down",                            # mlp / shared moe
+    "w_up", "w_gate", "w_down",                            # mlp / moe experts
     "w_in", "w_out",                                       # mamba2 mixer
 })
 
-#: dict keys whose subtrees hold quant_matmul-consumed projections.  MoE
-#: routed experts live directly under "moe" (stacked (E, ...) einsum
-#: operands sharing the mlp leaf NAMES) — only the "shared" expert subtree
-#: routes through quant_matmul, so parent-key scoping is load-bearing.
-_QUANT_PARENT_KEYS = frozenset({"attn", "mlp", "m", "shared"})
+#: dict keys whose subtrees hold frozen projections.  MoE routed experts
+#: live directly under "moe" as stacked (E, in, out) leaves sharing the
+#: mlp leaf NAMES: each expert's matrix is frozen with its own
+#: per-output-column scale (``quantize_weight`` encodes stacked leaves
+#: slice by slice); the router beside them is no target.
+_QUANT_PARENT_KEYS = frozenset({"attn", "mlp", "m", "shared", "moe"})
 
 
 #: EngineConfig(quant=...) mode -> (weight kernel, residual prune threshold).
@@ -305,8 +308,8 @@ def quantize_decode_params(params, quant: str):
     (full-table NF4 dequant — the test oracle, not an engine mode).  A
     leaf is quantized iff its dict key is in ``DECODE_QUANT_TARGETS``, some
     ancestor key is in the quant-parent set, and it is a float matrix —
-    everything else (norms, embeddings, routers, MoE routed experts, MLA
-    w_uk/w_uv) passes through untouched.
+    everything else (norms, embeddings, routers, MLA w_uk/w_uv) passes
+    through untouched.
     """
     kernel, prune = DECODE_QUANT_KERNELS[quant]
 

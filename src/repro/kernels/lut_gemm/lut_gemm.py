@@ -25,6 +25,9 @@ kernels implement the paper's two select-tree organizations:
   one 16-entry table on the scalar unit (bitwise the per-element values)
   and evaluates it with one 15-select tree.  It is the TPU path of the
   engine's ``nf4_dc`` decode matmul (``ops.nf4_dc_matmul``).
+* :func:`lut_gemm_dc_res_experts` — the same block over a stack of
+  experts in one call (an expert axis in the grid), the TPU path of an
+  MoE layer's frozen routed experts (``ops.nf4_dc_expert_matmul``).
 
 Memory layout per grid step of ``lut_gemm`` and ``lut_gemm_dc``: x tile
 (bm, bk) bf16/f32, codes tile (bk, bn) int8, dequantized tile (bk, bn)
@@ -157,16 +160,31 @@ def _lut_gemm_dc_kernel(x_ref, codes_ref, hi_ref, lo_ref, zp_ref, scale_ref,
 
 def _lut_gemm_dc_res_kernel(x_ref, codes_ref, hi_ref, lo_ref, res_ref,
                             zp_ref, scale_ref, o_ref, *, bk: int):
+    _dc_res_block(x_ref, codes_ref, hi_ref, lo_ref, res_ref, 0, zp_ref,
+                  scale_ref, o_ref, bk=bk)
+
+
+def _lut_gemm_dc_res_experts_kernel(x_ref, codes_ref, hi_ref, lo_ref,
+                                    res_ref, zp_ref, scale_ref, o_ref, *,
+                                    bk: int):
+    # grid axis 0 walks the experts; each holds its own tables in SMEM
+    _dc_res_block(x_ref, codes_ref, hi_ref, lo_ref, res_ref,
+                  pl.program_id(0), zp_ref, scale_ref, o_ref, bk=bk)
+
+
+def _dc_res_block(x_ref, codes_ref, hi_ref, lo_ref, res_ref, t, zp_ref,
+                  scale_ref, o_ref, *, bk: int):
+    """One (bm, bn) output block of the residual-corrected D&C GEMM, with
+    row ``t`` of the (T, 4) / (T, 16) tables in SMEM."""
     # the 6-select D&C sum and the per-code residual gather, folded into
     # one 16-entry table on the scalar unit in the per-element order
     # (HI[q>>2] + LO[q&3]) + RES[q]: the same values bit for bit, for one
     # 15-select tree per element instead of 6 + 15 selects and two adds
-    table = [(hi_ref[0, q >> 2] + lo_ref[0, q & 3]) + res_ref[0, q]
+    table = [(hi_ref[t, q >> 2] + lo_ref[t, q & 3]) + res_ref[t, q]
              for q in range(16)]
     zp = zp_ref[...]                                     # (1, bn)
 
-    def k_chunk(c, acc):
-        rows = pl.ds(pl.multiple_of(c * bk, bk), bk)
+    def k_chunk(rows, acc):
         w = _mux_tree_dequant(codes_ref[rows, :].astype(jnp.int32),
                               table) - zp                # (bk, bn) f32
         x = x_ref[:, rows].astype(jnp.float32)
@@ -174,8 +192,17 @@ def _lut_gemm_dc_res_kernel(x_ref, codes_ref, hi_ref, lo_ref, res_ref,
             x, w, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    acc = jax.lax.fori_loop(0, codes_ref.shape[0] // bk, k_chunk,
-                            jnp.zeros(o_ref.shape, jnp.float32))
+    zero = jnp.zeros(o_ref.shape, jnp.float32)
+    nk = codes_ref.shape[0] // bk
+    if nk == 1:
+        # one chunk is the whole K extent: read it whole, since a K that
+        # is no multiple of 128 (DeepSeek-V2-Lite's dense 10944) cannot be
+        # sliced at a lane offset the loop computes
+        acc = k_chunk(slice(None), zero)
+    else:
+        acc = jax.lax.fori_loop(
+            0, nk, lambda c, a: k_chunk(
+                pl.ds(pl.multiple_of(c * bk, bk), bk), a), zero)
     o_ref[...] = (acc * scale_ref[...]).astype(o_ref.dtype)  # (1, bn) bcast
 
 
@@ -232,6 +259,53 @@ def lut_gemm_dc_res(x: jax.Array, w_codes: jax.Array, hi_tab: jax.Array,
         interpret=interpret,
     )(x, w_codes, hi_tab.reshape(1, 4), lo_tab.reshape(1, 4),
       residual.reshape(1, 16), zero_point.reshape(1, n), scale.reshape(1, n))
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def lut_gemm_dc_res_experts(x: jax.Array, w_codes: jax.Array,
+                            hi_tab: jax.Array, lo_tab: jax.Array,
+                            residual: jax.Array, zero_point: jax.Array,
+                            scale: jax.Array, *, bm: int = DEFAULT_BM,
+                            bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
+                            interpret: bool = False) -> jax.Array:
+    """:func:`lut_gemm_dc_res` over a stack of experts in one call:
+    ``out[e] = x[e] @ ((HI_e[q>>2] + LO_e[q&3] + RES_e[q] - zp_e) *
+    scale_e)`` with ``q = w_codes[e]``.
+
+    x: (E, M, K) float; w_codes: (E, K, N) int8; hi_tab/lo_tab: (E, 4);
+    residual: (E, 16); zero_point/scale: (E, N).  Returns (E, M, N) in
+    ``x``'s dtype.  Grid ``(E, cdiv(M, bm), cdiv(N, bn))``: the expert
+    axis outermost, each step the block of :func:`lut_gemm_dc_res` with
+    that expert's tables, so one expert's codes are read once per row
+    block.  Named ``lut_gemm_dc_res_experts`` in a profiler trace.
+    """
+    e, m, k = x.shape
+    e2, k2, n = w_codes.shape
+    assert e == e2 and k == k2, (x.shape, w_codes.shape)
+    assert hi_tab.shape == (e, 4) and lo_tab.shape == (e, 4)
+    assert residual.shape == (e, 16)
+    assert k % bk == 0, (k, bk)
+
+    return pl.pallas_call(
+        functools.partial(_lut_gemm_dc_res_experts_kernel, bk=bk),
+        grid=(e, pl.cdiv(m, bm), pl.cdiv(n, bn)),
+        in_specs=[
+            pl.BlockSpec((None, bm, k), lambda g, i, j: (g, i, 0)),
+            pl.BlockSpec((None, k, bn), lambda g, i, j: (g, 0, j)),
+            _SMEM,
+            _SMEM,
+            _SMEM,
+            pl.BlockSpec((None, 1, bn), lambda g, i, j: (g, 0, j)),
+            pl.BlockSpec((None, 1, bn), lambda g, i, j: (g, 0, j)),
+        ],
+        out_specs=pl.BlockSpec((None, bm, bn), lambda g, i, j: (g, i, j)),
+        out_shape=jax.ShapeDtypeStruct((e, m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_bytes(bm, k, bn, bk)),
+        name="lut_gemm_dc_res_experts",
+        interpret=interpret,
+    )(x, w_codes, hi_tab, lo_tab, residual, zero_point.reshape(e, 1, n),
+      scale.reshape(e, 1, n))
 
 
 def _vmem_bytes(bm: int, k: int, bn: int, bk: int) -> int:

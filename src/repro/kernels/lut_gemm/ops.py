@@ -21,6 +21,11 @@ The entry points over the LUT kernels:
 * :func:`nf4_dc_matmul` — an ``nf4_dc`` weight through the fused
   ``lut_gemm_dc_res`` Pallas kernel: codes dequantized in VMEM, tiles
   chosen from the shapes.
+* :func:`quantized_expert_matmul` — :func:`quantized_matmul` over a
+  stack of experts (an MoE layer's frozen routed experts): on the TPU an
+  ``nf4_dc`` stack runs :func:`nf4_dc_expert_matmul`, one
+  ``lut_gemm_dc_res_experts`` call for every expert; elsewhere the
+  ``jnp`` path of each expert.
 * :func:`nf4_matmul_kernel` — NF4 codebook weights through the full-table
   Pallas kernel (paper Fig 1 select tree, programmable codebook).
 * :func:`lut4_matmul_kernel` — uniform-int4 weights through the D&C
@@ -39,7 +44,8 @@ import jax.numpy as jnp
 from repro.core.lut import NF4_CODEBOOK, codebook_dequant
 from repro.core.quant import QuantizedWeight, dequantize, quantize_weight
 from repro.kernels.lut_gemm.lut_gemm import (lut_gemm, lut_gemm_dc,
-                                             lut_gemm_dc_res)
+                                             lut_gemm_dc_res,
+                                             lut_gemm_dc_res_experts)
 
 
 def codebook_quantize(w: jax.Array, codebook: jax.Array
@@ -98,6 +104,24 @@ def _jnp_matmul(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
     return (x.astype(jnp.float32) @ w).astype(x.dtype)
 
 
+def quantized_expert_matmul(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
+    """``out[e] = x[e] @ dequant(qw[e])`` for a stack of experts.
+
+    ``x``: (E, M, K) float; ``qw.codes``: (E, K, N) (a layer's slice of
+    the stacked routed experts).  Returns (E, M, N) in ``x``'s dtype.
+    Lowered for the TPU, ``nf4_dc`` runs :func:`nf4_dc_expert_matmul`;
+    otherwise each expert takes the ``jnp`` path, its reference."""
+    assert qw.codes.ndim == 3 and x.ndim == 3, (x.shape, qw.codes.shape)
+    if qw.kernel == "nf4_dc":
+        return jax.lax.platform_dependent(x, qw, tpu=nf4_dc_expert_matmul,
+                                          default=_jnp_expert_matmul)
+    return _jnp_expert_matmul(x, qw)
+
+
+def _jnp_expert_matmul(x: jax.Array, qw: QuantizedWeight) -> jax.Array:
+    return jax.vmap(_jnp_matmul)(x, qw)
+
+
 #: code bytes in one block of :func:`nf4_dc_matmul`'s grid: large enough
 #: that a decode projection takes a few grid steps (each costs about
 #: 0.35 us), small enough that the block's DMA, exposed before the first
@@ -131,6 +155,27 @@ def nf4_dc_matmul(x: jax.Array, qw: QuantizedWeight, *,
                           qw.zero_point, qw.scale, bm=bm, bn=bn, bk=bk,
                           interpret=interpret)
     return out.reshape(*x.shape[:-1], n)
+
+
+def nf4_dc_expert_matmul(x: jax.Array, qw: QuantizedWeight, *,
+                         interpret: bool = False) -> jax.Array:
+    """``out[e] = x[e] @ dequant(qw[e])`` for a stack of ``nf4_dc``
+    experts through one :func:`lut_gemm_dc_res_experts` call.
+
+    ``x``: (E, M, K); ``qw.codes``: (E, K, N).  Tiles as in
+    :func:`nf4_dc_matmul`, per expert, except that ``bk`` is the largest
+    of 256 and 128 that divides K (an expert's 1408-wide down-projection
+    takes 128), so that no step dequantizes the whole K extent at once.
+    """
+    e, k, n = qw.codes.shape
+    m = x.shape[1]
+    bm = min(-(-m // 16) * 16, 256)
+    cap = max(128, DC_RES_BLOCK_BYTES // k // 128 * 128)
+    bn = n if n <= cap else cap
+    bk = next((b for b in (DC_RES_BK, 128) if k % b == 0), k)
+    return lut_gemm_dc_res_experts(x, qw.codes, qw.hi_tab, qw.lo_tab,
+                                   qw.residual, qw.zero_point, qw.scale,
+                                   bm=bm, bn=bn, bk=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from repro.core.layers import quant_matmul
 from repro.models.common import (apply_rope, dense_init, dense_write_window,
                                  paged_gather, paged_write,
-                                 paged_write_window)
+                                 paged_write_window, rms_norm,
+                                 yarn_get_mscale)
 
 
 class KVCache(NamedTuple):
@@ -309,6 +310,9 @@ def init_mla(key, cfg):
         "w_uk": dense_init(ks[1], m.kv_lora_rank, h * m.qk_nope_dim, dt),
         "w_uv": dense_init(ks[2], m.kv_lora_rank, h * m.v_dim, dt),
         "wo": dense_init(ks[3], h * m.v_dim, d, dt),
+        # RMSNorm gain on the compressed KV (kv_a_layernorm), applied
+        # before it is cached and up-projected
+        "kv_norm": {"norm_w": jnp.ones((m.kv_lora_rank,), jnp.float32)},
     }
     if m.q_lora_rank:
         p["w_dq"] = dense_init(ks[4], d, m.q_lora_rank, dt)
@@ -318,6 +322,18 @@ def init_mla(key, cfg):
     return p
 
 
+def mla_softmax_scale(cfg) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times YaRN's
+    ``yarn_get_mscale(factor, mscale_all_dim) ** 2`` under rope scaling
+    (DeepSeek-V2's ``DeepseekV2Attention.softmax_scale``)."""
+    m = cfg.mla
+    scale = float(m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_get_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_attention(params, x: jax.Array, cfg, *, positions: jax.Array,
                   cache: KVCache | None = None, cache_index=None,
                   block_table=None, n_valid=None):
@@ -325,6 +341,9 @@ def mla_attention(params, x: jax.Array, cfg, *, positions: jax.Array,
 
     Cache stores (c_kv (B,S,R), k_rope (B,S,dr)) — the 'absorbed' form keeps
     decode FLOPs at O(R + dr) per head instead of materializing per-head K/V.
+    ``c_kv`` is cached after its RMSNorm (``kv_norm``), as DeepSeek-V2's
+    ``kv_a_layernorm`` normalizes it before the up-projection; rope is
+    YaRN-scaled when ``cfg.rope_scaling`` is set.
     ``block_table``: (B, nblk) — cache leaves are paged pools
     (num_blocks, block_size, R) / (num_blocks, block_size, dr); ``n_valid``:
     (B,) real-token counts for an S > 1 speculative verify window; see
@@ -342,11 +361,12 @@ def mla_attention(params, x: jax.Array, cfg, *, positions: jax.Array,
         q = quant_matmul(x, params["wq"], cfg.quant, "attn")
     q = q.reshape(b, s, h, qd)
     q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     dkv = quant_matmul(x, params["w_dkv"], cfg.quant, "attn")
     c_kv, k_rope = dkv[..., :m.kv_lora_rank], dkv[..., m.kv_lora_rank:]
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    c_kv = rms_norm(c_kv, params["kv_norm"]["norm_w"], cfg.norm_eps)
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     kv_len = None
     q_offset = 0
@@ -365,7 +385,7 @@ def mla_attention(params, x: jax.Array, cfg, *, positions: jax.Array,
             ctx_c, c_all, r_all = sharded_mla_decode(
                 q_abs, q_rope.astype(jnp.float32), cache.k, cache.v,
                 c_kv, k_rope, cache_index, mesh,
-                sm_scale=1.0 / float(qd) ** 0.5, block_table=block_table)
+                sm_scale=mla_softmax_scale(cfg), block_table=block_table)
             w_uv = params["w_uv"].reshape(m.kv_lora_rank, h, m.v_dim)
             ctx = jnp.einsum("bqhr,rhd->bqhd", ctx_c.astype(jnp.float32),
                              w_uv.astype(jnp.float32))
@@ -419,12 +439,12 @@ def mla_attention(params, x: jax.Array, cfg, *, positions: jax.Array,
                        w_uk.astype(jnp.float32))          # (B,Sq,H,R)
     c_f = c_kv.astype(jnp.float32)
     r_f = k_rope.astype(jnp.float32)
-    inv_sqrt = 1.0 / jnp.sqrt(qd).astype(jnp.float32)
+    sm_scale = mla_softmax_scale(cfg)
 
     def _chunk(qa, qr, off):
         s_c = jnp.einsum("bqhr,bkr->bhqk", qa, c_f)
         s_r = jnp.einsum("bqhd,bkd->bhqk", qr, r_f)
-        scores = (s_c + s_r) * inv_sqrt
+        scores = (s_c + s_r) * sm_scale
         bias = _bias(qa.shape[1], sk, off, True, kv_len)
         if bias.ndim == 2:            # scalar offsets: broadcast over (B, H)
             bias = bias[None, None]

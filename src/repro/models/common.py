@@ -1,6 +1,7 @@
 """Shared model building blocks (pure-functional, param-dict style)."""
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import jax
@@ -160,19 +161,70 @@ def gather_last(hidden: jax.Array, last_pos) -> jax.Array:
     return jnp.take_along_axis(hidden, idx, axis=1)
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse rope frequencies; ``scaling``: a
+    :class:`~repro.configs.base.RopeScaling` (YaRN) or None."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if scaling is None:
+        return freqs
+    # DeepSeek-V2's DeepseekV2YarnRotaryEmbedding: frequencies below the
+    # correction range keep their value, those above it are divided by
+    # the factor, and a linear ramp joins the two
+    low, high = yarn_correction_range(scaling, head_dim, theta)
+    mask = 1.0 - yarn_linear_ramp_mask(low, high, head_dim // 2)
+    return freqs / scaling.factor * (1.0 - mask) + freqs * mask
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D) or (..., S, D); positions: broadcastable to (..., S)."""
+def yarn_correction_dim(rotations: float, dim: int, theta: float,
+                        max_positions: int) -> float:
+    """The rope dimension that turns ``rotations`` times over
+    ``max_positions`` (``yarn_find_correction_dim``)."""
+    return (dim * math.log(max_positions / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def yarn_correction_range(scaling, dim: int, theta: float
+                          ) -> tuple[int, int]:
+    """``yarn_find_correction_range``: the rope dimensions between
+    ``beta_fast`` and ``beta_slow`` rotations."""
+    n = scaling.original_max_position_embeddings
+    low = math.floor(yarn_correction_dim(scaling.beta_fast, dim, theta, n))
+    high = math.ceil(yarn_correction_dim(scaling.beta_slow, dim, theta, n))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_linear_ramp_mask(low: float, high: float, n: int) -> jax.Array:
+    """``yarn_linear_ramp_mask``: 0 up to ``low``, 1 from ``high``."""
+    if low == high:
+        high += 0.001
+    ramp = (jnp.arange(n, dtype=jnp.float32) - low) / (high - low)
+    return jnp.clip(ramp, 0.0, 1.0)
+
+
+def yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
+    """``yarn_get_mscale``: YaRN's attention temperature."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: (..., S, H, D) or (..., S, D); positions: broadcastable to (..., S).
+    ``scaling``: YaRN (:class:`~repro.configs.base.RopeScaling`) or None,
+    which is plain rope."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (d/2,)
+    freqs = rope_freqs(d, theta, scaling)              # (d/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, d/2)
     if x.ndim == angles.ndim + 1:                      # head axis present
         angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = (yarn_get_mscale(scaling.factor, scaling.mscale)
+             / yarn_get_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
     o1 = x1 * cos - x2 * sin
     o2 = x2 * cos + x1 * sin

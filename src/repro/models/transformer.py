@@ -42,13 +42,16 @@ def _block_init(key, cfg, *, use_moe: bool, d_ff: int | None = None):
 
 
 def _block_apply(p, x, cfg, *, positions, cache, cache_index, use_moe: bool,
-                 block_tables=None, n_valid=None):
+                 block_tables=None, n_valid=None, training: bool = False):
+    """One block: returns (x, new_cache, aux loss, routed experts
+    (B, S, top_k) int32 of an MoE block, else None)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.mla:
-        a, new_cache = attn_mod.mla_attention(
-            p["attn"], h, cfg, positions=positions, cache=cache,
-            cache_index=cache_index, block_table=block_tables,
-            n_valid=n_valid)
+        with jax.named_scope("mla.attn"):
+            a, new_cache = attn_mod.mla_attention(
+                p["attn"], h, cfg, positions=positions, cache=cache,
+                cache_index=cache_index, block_table=block_tables,
+                n_valid=n_valid)
     else:
         a, new_cache = attn_mod.gqa_attention(
             p["attn"], h, cfg, positions=positions, cache=cache,
@@ -57,12 +60,12 @@ def _block_apply(p, x, cfg, *, positions, cache, cache_index, use_moe: bool,
     x = x + a
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
+    routes = None
     if use_moe:
-        f, aux = moe_mod.moe_ffn(p["moe"], h, cfg,
-                                 window=n_valid is not None)
+        f, aux, routes = moe_mod.moe_ffn(p["moe"], h, cfg, training=training)
     else:
         f = mlp(p["mlp"], h, cfg)
-    return x + f, new_cache, aux
+    return x + f, new_cache, aux, routes
 
 
 class TransformerLM:
@@ -97,7 +100,10 @@ class TransformerLM:
 
     # ---------------- forward ----------------
     def _scan_blocks(self, params, x, *, positions, caches, cache_index,
-                     training: bool, block_tables=None, n_valid=None):
+                     training: bool, block_tables=None, n_valid=None,
+                     routes: bool = False):
+        """``routes``: the scan also yields each MoE block's routed
+        experts, and the new caches come back as (caches, routes)."""
         cfg = self.cfg
         use_moe = cfg.moe is not None
         from repro.parallel.act_sharding import shard_hidden
@@ -106,11 +112,13 @@ class TransformerLM:
             h, aux = carry
             p_i, cache_i = xs
             h = shard_hidden(h)
-            h2, new_cache, aux_i = _block_apply(
+            h2, new_cache, aux_i, top_e = _block_apply(
                 p_i, h, cfg, positions=positions, cache=cache_i,
                 cache_index=cache_index, use_moe=use_moe,
-                block_tables=block_tables, n_valid=n_valid)
-            return (shard_hidden(h2), aux + aux_i), new_cache
+                block_tables=block_tables, n_valid=n_valid,
+                training=training)
+            ys = (new_cache, top_e) if routes else new_cache
+            return (shard_hidden(h2), aux + aux_i), ys
 
         if training and cfg.remat:
             body = jax.checkpoint(
@@ -143,8 +151,10 @@ class TransformerLM:
 
     def forward(self, params, tokens=None, *, embeds=None, caches=None,
                 cache_index=0, training: bool = False, block_tables=None,
-                n_valid=None):
-        """Returns (hidden (B,S,D), aux, new_caches)."""
+                n_valid=None, routes: bool = False):
+        """Returns (hidden (B,S,D), aux, new_caches); with ``routes``
+        (MoE) also the routed experts of every MoE block,
+        (L_moe, B, S, top_k) int32."""
         cfg = self.cfg
         if embeds is None:
             embeds = params["embed"][tokens]
@@ -159,7 +169,7 @@ class TransformerLM:
         new_dense_caches = []
         for i in range(n_dense):
             c = dense_caches[i] if dense_caches is not None else None
-            x, nc, _ = _block_apply(
+            x, nc, _, _ = _block_apply(
                 params["dense_blocks"][i], x, cfg, positions=positions,
                 cache=c, cache_index=cache_index, use_moe=False,
                 block_tables=block_tables, n_valid=n_valid)
@@ -169,9 +179,13 @@ class TransformerLM:
             caches=scan_caches if scan_caches is not None else _none_caches(
                 cfg.num_layers - n_dense),
             cache_index=cache_index, training=training,
-            block_tables=block_tables, n_valid=n_valid)
+            block_tables=block_tables, n_valid=n_valid, routes=routes)
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        if routes:
+            new_scan, top_e = new_scan
         new_caches = (new_dense_caches, new_scan) if caches is not None else None
+        if routes:
+            return x, aux, new_caches, top_e
         return x, aux, new_caches
 
     def logits(self, params, hidden):
@@ -243,7 +257,8 @@ class TransformerLM:
         logits = self.logits(params, last)
         return logits, new_caches
 
-    def decode_step(self, params, token, state, index, *, tables=None):
+    def decode_step(self, params, token, state, index, *, tables=None,
+                    routes: bool = False):
         """token: (B, 1) int32; index: scalar int32 position shared by all
         rows, or a (B,) int32 array of per-row positions (mixed-depth
         continuous batching).  ``tables``: (B, nblk) int32 block tables
@@ -253,11 +268,14 @@ class TransformerLM:
         (``EngineConfig(quant=...)``): attention/MLP projection leaves are
         then ``QuantizedWeight`` containers that ``quant_matmul`` routes
         through the D&C LUT gemm — scan-stacked leaves slice per layer
-        like any float leaf (registered pytree with a leading L axis)."""
-        hidden, _, new_caches = self.forward(
-            params, token, caches=state, cache_index=index,
-            block_tables=tables)
-        return self.logits(params, hidden), new_caches
+        like any float leaf (registered pytree with a leading L axis).
+
+        ``routes`` (MoE): also return the routed experts of every MoE
+        block, (L_moe, B, 1, top_k) int32."""
+        out = self.forward(params, token, caches=state, cache_index=index,
+                           block_tables=tables, routes=routes)
+        logits = self.logits(params, out[0])
+        return (logits, out[2], out[3]) if routes else (logits, out[2])
 
     def decode_window(self, params, tokens, state, index, *, tables=None,
                       n_valid=None, last_pos=None):

@@ -19,16 +19,16 @@ the recording-overhead bound.
 """
 from repro.obs.exporters import (dump_metrics, dump_trace,
                                  start_metrics_server)
-from repro.obs.registry import (ITL_BUCKETS, PHASE_BUCKETS,
-                                SPEC_REQUEST_BUCKETS, SPEC_WINDOW_BUCKETS,
-                                TTFT_BUCKETS, Counter, Gauge, Histogram,
-                                MetricsRegistry)
+from repro.obs.registry import (EXPERT_LOAD_BUCKETS, ITL_BUCKETS,
+                                PHASE_BUCKETS, SPEC_REQUEST_BUCKETS,
+                                SPEC_WINDOW_BUCKETS, TTFT_BUCKETS, Counter,
+                                Gauge, Histogram, MetricsRegistry)
 from repro.obs.trace import TraceEvent, Tracer, perfetto_json
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "TTFT_BUCKETS", "ITL_BUCKETS", "PHASE_BUCKETS",
-    "SPEC_WINDOW_BUCKETS", "SPEC_REQUEST_BUCKETS",
+    "SPEC_WINDOW_BUCKETS", "SPEC_REQUEST_BUCKETS", "EXPERT_LOAD_BUCKETS",
     "TraceEvent", "Tracer", "perfetto_json",
     "start_metrics_server", "dump_metrics", "dump_trace",
 ]

@@ -41,6 +41,9 @@ PHASE_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
 #: totals per retired request
 SPEC_WINDOW_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0)
 SPEC_REQUEST_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
+#: tokens the busiest held routed expert takes in one decode step
+EXPERT_LOAD_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0,
+                       256.0)
 
 
 def _label_key(labels: dict) -> tuple:

@@ -89,9 +89,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.moe import held_load
 from repro.models.registry import get_model
-from repro.obs import (ITL_BUCKETS, PHASE_BUCKETS, SPEC_REQUEST_BUCKETS,
-                       SPEC_WINDOW_BUCKETS, TTFT_BUCKETS, MetricsRegistry,
+from repro.obs import (EXPERT_LOAD_BUCKETS, ITL_BUCKETS, PHASE_BUCKETS,
+                       SPEC_REQUEST_BUCKETS, SPEC_WINDOW_BUCKETS,
+                       TTFT_BUCKETS, MetricsRegistry,
                        Tracer)
 from repro.obs.trace import PHASE_EVENTS
 from repro.serve.backend import make_backend
@@ -742,6 +744,20 @@ class Engine:
         self._g_free = reg.gauge(
             "engine_pool_free_capacity",
             "backend free capacity (dense: slots; paged: blocks)")
+        if self.cfg.moe is not None:
+            # routed-expert load, from the (MoE layers, held) counts the
+            # decode program returns beside its tokens
+            self._c_pairs_held = reg.counter(
+                "engine_expert_pairs_held_total",
+                "decode token-expert pairs routed to an expert held here")
+            self._c_pairs_routed = reg.counter(
+                "engine_expert_pairs_routed_total",
+                "decode token-expert pairs routed (top_k per token per "
+                "MoE layer)")
+            self._h_expert_busiest = reg.histogram(
+                "engine_expert_busiest_tokens",
+                "tokens of the busiest held expert of any MoE layer in "
+                "one decode step", buckets=EXPERT_LOAD_BUCKETS)
         reg.gauge(
             "engine_info",
             "static engine identity (value is always 1)",
@@ -813,11 +829,21 @@ class Engine:
 
     def _decode_impl(self, params, tokens, caches, positions, tables, rids,
                      steps, key):
-        logits, new_caches = self.model.decode_step(
-            params, tokens, caches, positions, tables=tables)
+        """One decode step; an MoE model's also returns the (MoE layers,
+        held experts) int32 counts of the active rows' routed tokens."""
+        moe = self.cfg.moe
+        if moe is None:
+            logits, new_caches = self.model.decode_step(
+                params, tokens, caches, positions, tables=tables)
+        else:
+            logits, new_caches, routes = self.model.decode_step(
+                params, tokens, caches, positions, tables=tables,
+                routes=True)
         toks = sample(logits[:, 0], key, self.sampling, rids=rids,
                       steps=steps)
-        return toks, new_caches
+        if moe is None:
+            return toks, new_caches
+        return toks, new_caches, held_load(routes, rids >= 0, moe)
 
     def _verify_impl(self, params, tokens, caches, positions, tables,
                      n_valid, last_pos):
@@ -1525,15 +1551,19 @@ class Engine:
                     jnp.asarray(self.positions), tables, jnp.asarray(rids),
                     jnp.asarray(steps))
         with self._phase("decode", batch=n_active) as ph:
-            nxt, self.caches = self._decode(self.decode_params, *args,
-                                            self.key)
+            out = self._decode(self.decode_params, *args, self.key)
+            self.caches = out[1]
             del args               # frees the old caches, as before
             with self._phase("decode_wait"):
-                nxt = np.asarray(nxt)
+                # one transfer: the tokens, and an MoE step's expert load
+                got = jax.device_get((out[0],) + out[2:])
+            nxt = got[0]
         self.metrics.decode_s += ph.dur
         self.metrics.ticks += 1
         self.metrics.occupancy_sum += n_active
         self.metrics.decode_tokens += n_active
+        if len(got) > 1:
+            self._note_expert_load(got[1], n_active)
         with self._phase("emit"):
             for s, req in enumerate(self.slots):
                 if req is None or req.rid not in self.active:
@@ -1549,6 +1579,13 @@ class Engine:
                     self._retire(req)
                     self.active.pop(req.rid, None)
                     self._free_slot(s)
+
+    def _note_expert_load(self, load: np.ndarray, n_active: int):
+        """Count one decode step's (MoE layers, held experts) load."""
+        self._c_pairs_held.add(int(load.sum()))
+        self._c_pairs_routed.add(n_active * self.cfg.moe.top_k
+                                 * load.shape[0])
+        self._h_expert_busiest.observe(float(load.max()))
 
     def _spec_tick(self) -> bool:
         """One speculative advance: draft -> batched verify -> accept

@@ -179,6 +179,27 @@ def test_nf4_dc_tpu_route_matches_jnp_path(monkeypatch, x_shape, k, n,
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("m", [5, 32])
+def test_nf4_dc_expert_route_matches_jnp_path(m):
+    """The TPU route of ``quantized_expert_matmul`` (one
+    ``lut_gemm_dc_res_experts`` call over the stack, in interpret mode)
+    against each expert's jnp path: equal to f32 rounding.  A K of 384
+    takes ``bk`` = 128; N = 200 ends in a ragged block."""
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.normal(size=(3, m, 384)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(3, 384, 200)) * 0.05, jnp.float32)
+    qw = quantize_weight(w, "nf4_dc")
+    got = lut_ops.nf4_dc_expert_matmul(x, qw, interpret=True)
+    want = lut_ops.quantized_expert_matmul(x, qw)
+    per_expert = jnp.stack([
+        quantized_matmul(x[i], jax.tree.map(lambda a: a[i], qw))
+        for i in range(3)])
+    assert got.shape == (3, m, 200)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(per_expert))
+
+
 def test_nf4_dc_matches_direct_dequant_weights():
     """Residual-corrected D&C reconstructs the NF4 codebook exactly up to
     float rounding: the nf4_dc and nf4_dequant kernels produce the same
@@ -272,13 +293,15 @@ def test_quantized_greedy_agreement_above_threshold():
     assert agree / total >= 0.5, (agree, total)
 
 
-def test_nf4_tokens_identical_to_direct_dequant_oracle():
+@pytest.mark.parametrize("arch", ["yi-9b", "deepseek-v2-lite-16b"])
+def test_nf4_tokens_identical_to_direct_dequant_oracle(arch):
     """Acceptance pin: an nf4 engine (6-select D&C + residual correction)
     emits exactly the tokens of an engine whose decode tree is the direct
     full-table NF4 dequant oracle (15 selects) — the D&C split plus
-    residual loses nothing.  Prefill stays full precision, so the first
-    token also matches bf16 exactly."""
-    cfg, params = _setup()
+    residual loses nothing, on dense projections and (deepseek) on the
+    frozen routed experts too.  Prefill stays full precision, so the
+    first token also matches bf16 exactly."""
+    cfg, params = _setup(arch)
     prompts = _prompts(cfg)
     base, _ = _serve(cfg, params, prompts)
     nf4, _ = _serve(cfg, params, prompts, quant="nf4")
@@ -445,8 +468,9 @@ def test_quant_composes_with_paged_and_prefix_cache():
 
 def test_quantized_decode_all_served_families():
     """Every servable family decodes under lut4, with the exclusion rules
-    honored: MoE routed experts and MLA's direct-use w_uk/w_uv stay float
-    (they are einsum/reshape operands, not quant_matmul projections)."""
+    honored: MLA's direct-use w_uk/w_uv and the router stay float, while
+    the MoE routed experts are frozen, each expert's matrix with its own
+    per-output-column scale."""
     for arch in ("deepseek-v2-lite-16b", "mamba2-1.3b", "zamba2-1.2b"):
         cfg, params = _setup(arch)
         qp = quantize_decode_params(params, "lut4")
@@ -457,7 +481,10 @@ def test_quantized_decode_all_served_families():
         assert [o[0] for o in base] == [o[0] for o in lut], arch
         if cfg.family == "moe":
             moe = qp["blocks"]["moe"]
-            assert not isinstance(moe["w_up"], QuantizedWeight)
+            assert isinstance(moe["w_up"], QuantizedWeight)
+            layers, e, _, f = params["blocks"]["moe"]["w_up"].shape
+            assert moe["w_up"].scale.shape == (layers, e, f)
+            assert not isinstance(moe["router"], QuantizedWeight)
             assert isinstance(moe["shared"]["w_up"], QuantizedWeight)
 
 

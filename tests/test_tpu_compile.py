@@ -1,7 +1,8 @@
 """The Pallas kernels of the serving models compile for a TPU v5e chip.
 
-Each test lowers one kernel at zamba2-1.2b's real widths and compiles it
-for a *described* ``v5e:2x2`` topology: the TPU compiler runs here, no chip
+Each test lowers one kernel at zamba2-1.2b's or DeepSeek-V2-Lite's real
+widths and compiles it for a *described* ``v5e:2x2`` topology: the TPU
+compiler runs here, no chip
 is attached and nothing executes.  This catches what interpret mode cannot
 — tiling, memory-space and lowering refusals — at no chip time.
 
@@ -19,7 +20,8 @@ import pytest
 from repro.core.quant import QuantizedWeight
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.lut_gemm import ops as lut_ops
-from repro.kernels.lut_gemm.lut_gemm import lut_gemm_dc, lut_gemm_dc_res
+from repro.kernels.lut_gemm.lut_gemm import (lut_gemm_dc, lut_gemm_dc_res,
+                                             lut_gemm_dc_res_experts)
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 
@@ -33,6 +35,14 @@ DECODE_M = 8
 NF4_DECODE_SHAPES = [(2048, 8384), (4096, 2048), (2048, 2048), (2048, 8192),
                      (8192, 2048)]
 NF4_DECODE_ROWS = 12
+#: DeepSeek-V2-Lite's frozen decode projections (K, N): MLA wq, w_dkv and
+#: wo, the dense layer's 10944-wide FFN, the two shared experts' 2816;
+#: its 8 held routed experts of 1408 (gate/up, down); served 32 rows
+DSV2_DECODE_SHAPES = [(2048, 3072), (2048, 576), (2048, 2048),
+                      (2048, 10944), (10944, 2048), (2048, 2816),
+                      (2816, 2048)]
+DSV2_EXPERT_SHAPES = [(2048, 1408), (1408, 2048)]
+DSV2_HELD, DSV2_ROWS = 8, 32
 SSD_HEADS, SSD_P, SSD_N, SSD_CHUNK, SSD_SEQ = 64, 64, 64, 256, 512
 ATTN_HEADS, ATTN_D, ATTN_SEQ = 32, 64, 1024
 
@@ -90,18 +100,36 @@ def test_lut_gemm_compiles_for_v5e(sds, kernel, k, n):
                  x, codes, tab4, tab4, sds((16,), f32), chan, chan)
 
 
-@pytest.mark.parametrize("k,n", NF4_DECODE_SHAPES)
-def test_nf4_decode_matmul_takes_fused_kernel_on_v5e(sds, k, n):
+def _nf4_weight(sds, lead, k, n):
+    f32 = jnp.float32
+    chan, tab4 = sds(lead + (n,), f32), sds(lead + (4,), f32)
+    return QuantizedWeight(sds(lead + (k, n), jnp.int8), chan, chan, tab4,
+                           tab4, sds(lead + (16,), f32), kernel="nf4_dc")
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    *[(NF4_DECODE_ROWS, k, n) for k, n in NF4_DECODE_SHAPES],
+    *[(DSV2_ROWS, k, n) for k, n in DSV2_DECODE_SHAPES]])
+def test_nf4_decode_matmul_takes_fused_kernel_on_v5e(sds, rows, k, n):
     """Lowered for the TPU, an ``nf4_dc`` decode matmul runs the Pallas
     LUT kernel and holds no float copy of the weight: its temporaries
     stay below one f32 (K, N) weight (the ``jnp`` path's mux tree needs
     about twenty)."""
-    f32 = jnp.float32
-    chan, tab4 = sds((n,), f32), sds((4,), f32)
-    qw = QuantizedWeight(sds((k, n), jnp.int8), chan, chan, tab4, tab4,
-                         sds((16,), f32), kernel="nf4_dc")
-    x = sds((NF4_DECODE_ROWS, 1, k), jnp.bfloat16)
-    compiled = _compile(lut_ops.quantized_matmul, x, qw)
+    x = sds((rows, 1, k), jnp.bfloat16)
+    compiled = _compile(lut_ops.quantized_matmul, x,
+                        _nf4_weight(sds, (), k, n))
+    assert compiled.memory_analysis().temp_size_in_bytes < k * n * 4
+
+
+@pytest.mark.parametrize("k,n", DSV2_EXPERT_SHAPES)
+def test_nf4_expert_matmul_takes_named_kernel_on_v5e(sds, k, n):
+    """A decode step's held routed experts are one Pallas call over the
+    stack, named ``lut_gemm_dc_res_experts``, with no float copy of the
+    stacked weights."""
+    x = sds((DSV2_HELD, DSV2_ROWS, k), jnp.bfloat16)
+    compiled = _compile(lut_ops.quantized_expert_matmul, x,
+                        _nf4_weight(sds, (DSV2_HELD,), k, n))
+    assert "lut_gemm_dc_res_experts" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < k * n * 4
 
 
@@ -128,8 +156,9 @@ def test_flash_attention_compiles_for_v5e(sds):
 @pytest.mark.parametrize("fn", [
     lut_ops.nf4_matmul_kernel, lut_ops.lut4_matmul_kernel,
     lut_ops.nf4dc_matmul_kernel, lut_ops.nf4_dc_matmul,
-    ssd_ops.ssd_chunked_kernel,
-    lut_gemm_dc, lut_gemm_dc_res, ssd_scan, flash_attention,
+    lut_ops.nf4_dc_expert_matmul, ssd_ops.ssd_chunked_kernel,
+    lut_gemm_dc, lut_gemm_dc_res, lut_gemm_dc_res_experts, ssd_scan,
+    flash_attention,
 ], ids=lambda f: f.__name__)
 def test_kernels_do_not_default_to_interpret(fn):
     """On a chip a kernel compiles unless its caller asks for the
