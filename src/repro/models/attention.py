@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.layers import quant_matmul
+from repro.kernels.paged_attention.paged_attention import (
+    paged_decode_attention)
 from repro.models.common import (apply_rope, dense_init, dense_write_window,
                                  paged_gather, paged_write,
                                  paged_write_window, rms_norm,
@@ -196,7 +198,10 @@ def gqa_attention(params, x: jax.Array, cfg, *, positions: jax.Array,
     ``block_table``: (B, nblk) int32 — the cache leaves are then paged
     pools (num_blocks, block_size, ...) instead of dense (B, S, ...) slabs;
     decode writes at ``table[row, pos // bs]`` and attends over the gathered
-    logical-order view.  With per-row ``cache_index`` the decode write may
+    logical-order view, except that a plain one-token decode in f32
+    (``attn_f32``) lowered for the TPU runs the Pallas kernel
+    ``paged_decode_attention``, which reads each row's live blocks in
+    place.  With per-row ``cache_index`` the decode write may
     carry an S > 1 token window (speculative verify); ``n_valid``: optional
     (B,) count of real window tokens per row — the rest write nowhere and
     are masked out of attention via ``kv_len``.
@@ -252,14 +257,29 @@ def gqa_attention(params, x: jax.Array, cfg, *, positions: jax.Array,
                                             n_valid)
             valid = s if n_valid is None else n_valid
             new_cache = KVCache(k_pool, v_pool)
-            k = paged_gather(k_pool, block_table)
-            v = paged_gather(v_pool, block_table)
-            out = sdpa(q, k, v, causal=causal, q_offset=idx,
-                       kv_len=idx + valid,
-                       impl=impl or cfg.attn_impl, chunk=cfg.attn_chunk,
-                       unroll=not cfg.scan_layers, f32_operands=cfg.attn_f32,
-                       fused_mask=cfg.attn_fused_mask,
-                       causal_skip=cfg.attn_causal_skip)
+
+            def gathered(q, k_pool, v_pool):
+                return sdpa(q, paged_gather(k_pool, block_table),
+                            paged_gather(v_pool, block_table), causal=causal,
+                            q_offset=idx, kv_len=idx + valid,
+                            impl=impl or cfg.attn_impl, chunk=cfg.attn_chunk,
+                            unroll=not cfg.scan_layers,
+                            f32_operands=cfg.attn_f32,
+                            fused_mask=cfg.attn_fused_mask,
+                            causal_skip=cfg.attn_causal_skip)
+
+            def in_place(q, k_pool, v_pool):
+                return paged_decode_attention(q[:, 0], k_pool, v_pool,
+                                              block_table, idx + 1)[:, None]
+
+            if s == 1 and n_valid is None and cfg.attn_f32:
+                # plain decode: on the TPU a kernel reads each row's live
+                # blocks in place, the same f32 math as the gather path
+                out = jax.lax.platform_dependent(q, k_pool, v_pool,
+                                                 tpu=in_place,
+                                                 default=gathered)
+            else:
+                out = gathered(q, k_pool, v_pool)
             out = out.reshape(b, s, h * dh)
             return (quant_matmul(out, params["wo"], cfg.quant, "attn"),
                     new_cache)

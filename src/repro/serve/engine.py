@@ -758,6 +758,18 @@ class Engine:
                 "engine_expert_busiest_tokens",
                 "tokens of the busiest held expert of any MoE layer in "
                 "one decode step", buckets=EXPERT_LOAD_BUCKETS)
+        self._c_kv_read = self._c_kv_table = None
+        if self.paged and self.cfg.mla is None and self.cfg.attn_f32:
+            # the paged GQA decode whose attention, on the TPU, reads only
+            # the live blocks (models.attention): live blocks vs the table
+            self._c_kv_read = reg.counter(
+                "engine_attn_kv_blocks_read_total",
+                "KV pool blocks that hold the active rows' live positions "
+                "(the new token's included), summed over decode ticks")
+            self._c_kv_table = reg.counter(
+                "engine_attn_kv_blocks_table_total",
+                "block-table entries of the active rows, summed over decode "
+                "ticks")
         reg.gauge(
             "engine_info",
             "static engine identity (value is always 1)",
@@ -1564,6 +1576,13 @@ class Engine:
         self.metrics.decode_tokens += n_active
         if len(got) > 1:
             self._note_expert_load(got[1], n_active)
+        if self._c_kv_read is not None:
+            # a row at position p holds its live positions in
+            # ceil((p + 1) / bs) blocks
+            bs = self.backend.block_size
+            self._c_kv_read.add(int(np.sum(
+                (self.positions[rids >= 0] + bs) // bs)))
+            self._c_kv_table.add(n_active * self.backend.blocks_per_row)
         with self._phase("emit"):
             for s, req in enumerate(self.slots):
                 if req is None or req.rid not in self.active:

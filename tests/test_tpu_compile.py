@@ -15,6 +15,7 @@ import inspect
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.quant import QuantizedWeight
@@ -22,6 +23,8 @@ from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.lut_gemm import ops as lut_ops
 from repro.kernels.lut_gemm.lut_gemm import (lut_gemm_dc, lut_gemm_dc_res,
                                              lut_gemm_dc_res_experts)
+from repro.kernels.paged_attention.paged_attention import (
+    paged_decode_attention)
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 
@@ -43,6 +46,10 @@ DSV2_DECODE_SHAPES = [(2048, 3072), (2048, 576), (2048, 2048),
                       (2816, 2048)]
 DSV2_EXPERT_SHAPES = [(2048, 1408), (1408, 2048)]
 DSV2_HELD, DSV2_ROWS = 8, 32
+#: zamba2-1.2b's paged pool in the benchmark: 12 rows of 1536 positions
+#: in blocks of 16 (96 a row, 1153 with the garbage block), shared
+#: attention 32 heads of 64 over 32 KV heads
+PAGED_ROWS, PAGED_NBLK, PAGED_BLOCKS, PAGED_BS = 12, 96, 1153, 16
 SSD_HEADS, SSD_P, SSD_N, SSD_CHUNK, SSD_SEQ = 64, 64, 64, 256, 512
 ATTN_HEADS, ATTN_D, ATTN_SEQ = 32, 64, 1024
 
@@ -133,6 +140,99 @@ def test_nf4_expert_matmul_takes_named_kernel_on_v5e(sds, k, n):
     assert compiled.memory_analysis().temp_size_in_bytes < k * n * 4
 
 
+def _stored(topo, shape, dtype):
+    """A shape on one described chip in the layout an array is stored in
+    (major to minor, tiled): left unset, a compile for a described chip
+    chooses its own layouts for the program's inputs and outputs."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+    fmt = Format(Layout(tuple(range(len(shape)))),
+                 SingleDeviceSharding(topo.devices[0]))
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=fmt)
+
+
+@pytest.mark.parametrize("heads,kv_heads,dh", [(32, 32, 64), (32, 8, 128)],
+                         ids=["zamba2", "gqa-g4-dh128"])
+def test_paged_decode_attention_reads_pool_in_place_on_v5e(topo, heads,
+                                                          kv_heads, dh):
+    """The kernel, named ``paged_decode_attention``, reads the pools as
+    they are stored: no copy of a pool, no gather."""
+    pool = _stored(topo, (PAGED_BLOCKS, PAGED_BS, kv_heads, dh),
+                   jnp.bfloat16)
+    compiled = _compile(paged_decode_attention,
+                        _stored(topo, (PAGED_ROWS, heads, dh), jnp.bfloat16),
+                        pool, pool,
+                        _stored(topo, (PAGED_ROWS, PAGED_NBLK), jnp.int32),
+                        _stored(topo, (PAGED_ROWS,), jnp.int32))
+    hlo = compiled.as_text()
+    assert "paged_decode_attention" in hlo and " gather(" not in hlo
+    one_pool = PAGED_BLOCKS * PAGED_BS * kv_heads * dh * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_pool // 8
+
+
+def test_hybrid_decode_step_attends_in_place_on_v5e(topo):
+    """A hybrid decode step at zamba2-1.2b's widths and pool, two layers
+    deep with the shared block after each, lowered for the TPU: each
+    shared attention is the kernel, reading the pool its write has just
+    produced; nothing gathers a row's table, nothing transposes a pool, and
+    the only copies of a pool are the write's own copy of its input (the
+    caches are not donated, as in the engine).  Its temporaries stay under
+    one pool (the gather path's logical-order copies of K alone fill
+    one)."""
+    import re
+    from dataclasses import replace
+
+    from repro.models.common import CacheSpec
+    from repro.models.registry import get_config, get_model
+    base = get_config("zamba2-1.2b")
+    cfg = replace(base, num_layers=2, hybrid=replace(base.hybrid, period=1))
+    model = get_model(cfg)
+    hkv, dh = cfg.hybrid.shared_num_kv_heads, cfg.resolved_head_dim
+
+    def stored(tree):
+        return jax.tree.map(lambda a: _stored(topo, a.shape, a.dtype), tree)
+
+    def step(params, tokens, state, positions, tables):
+        return model.decode_step(params, tokens, state, positions,
+                                 tables=tables)
+
+    args = stored((
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((PAGED_ROWS, 1), jnp.int32),
+        jax.eval_shape(lambda: model.init_cache(
+            PAGED_ROWS, PAGED_NBLK * PAGED_BS,
+            spec=CacheSpec(PAGED_BS, PAGED_BLOCKS))),
+        jax.ShapeDtypeStruct((PAGED_ROWS,), jnp.int32),
+        jax.ShapeDtypeStruct((PAGED_ROWS, PAGED_NBLK), jnp.int32)))
+    outs = stored(jax.eval_shape(step, *args))
+    compiled = jax.jit(step, out_shardings=jax.tree.map(
+        lambda a: a.format, outs)).lower(*args).compile()
+    hlo = compiled.as_text()
+    pool = f"bf16[{PAGED_BLOCKS},{PAGED_BS},{hkv},{dh}]"
+    defs = {m[1]: (m[2], m[3], m[4]) for m in re.finditer(
+        r"^\s*(?:ROOT )?%(\S+) = (\S+?)\{[^}]*\} ([\w-]+)\(([^)]*)\)",
+        hlo, re.M)}
+    calls = [k for k, v in defs.items() if k.startswith(
+        "paged_decode_attention") and v[1] == "custom-call"]
+    assert len(calls) == 2
+    for name in calls:
+        for arg in re.findall(r"%([\w.-]+)", defs[name][2]):
+            if defs.get(arg, ("",))[0] == pool:   # the write's result
+                assert defs[arg][1] in ("fusion", "scatter"), defs[arg]
+    copies = [v for v in defs.values() if v[0] == pool and v[1] == "copy"]
+    assert len(copies) <= 4 and all(
+        defs[v[2].lstrip("%")][1] == "parameter" for v in copies)
+    assert not [v for v in defs.values()
+                if v[0] == pool and v[1] == "transpose"]
+    row_table = PAGED_NBLK * PAGED_BS * hkv * dh
+    for v in defs.values():
+        if v[1] == "gather":
+            dims = re.findall(r"\d+", v[0].split("[", 1)[1])
+            assert int(np.prod([int(x) for x in dims])) < row_table, v
+    one_pool = PAGED_BLOCKS * PAGED_BS * hkv * dh * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_pool
+
+
 def test_ssd_scan_compiles_for_v5e(sds):
     """The resumable, masked SSD chunk scan at one sequence's heads."""
     bh, f32 = SSD_HEADS, jnp.float32
@@ -158,7 +258,7 @@ def test_flash_attention_compiles_for_v5e(sds):
     lut_ops.nf4dc_matmul_kernel, lut_ops.nf4_dc_matmul,
     lut_ops.nf4_dc_expert_matmul, ssd_ops.ssd_chunked_kernel,
     lut_gemm_dc, lut_gemm_dc_res, lut_gemm_dc_res_experts, ssd_scan,
-    flash_attention,
+    flash_attention, paged_decode_attention,
 ], ids=lambda f: f.__name__)
 def test_kernels_do_not_default_to_interpret(fn):
     """On a chip a kernel compiles unless its caller asks for the
