@@ -2,6 +2,10 @@
 
 Each function prints ``name,us_per_call,derived`` CSV rows (derived = the
 paper-comparable quantity) and returns a dict for programmatic use.
+Timings here are CPU control-flow checks, not speed (``bench/`` measures
+speed on the TPU).
+
+Run:  PYTHONPATH=src python benchmarks/paper_tables.py
 """
 from __future__ import annotations
 
@@ -162,3 +166,22 @@ def fig18() -> dict:
 
 ALL = [table1, table2, fig5, fig6, fig8, fig12, fig13, fig14, fig15, fig16,
        fig18]
+
+
+def main() -> None:
+    """Print every table and figure as CSV; exit 1 if any failed."""
+    import sys
+    import traceback
+    print("name,us_per_call,derived")
+    failures = 0
+    for fn in ALL:
+        try:
+            fn()
+        except Exception:  # noqa: BLE001
+            failures += 1
+            print(f"{fn.__name__},FAIL,{traceback.format_exc(limit=1)!r}")
+    sys.exit(1 if failures else 0)
+
+
+if __name__ == "__main__":
+    main()

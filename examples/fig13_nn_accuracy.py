@@ -6,8 +6,7 @@ Extended with the serving-side PTQ columns: the bf16-trained ("ideal") net
 re-evaluated with its weights frozen to 4-bit ``QuantizedWeight`` leaves —
 exactly what ``EngineConfig(quant=...)`` does to decode projections.
 
-* affine pair (``lut4`` vs ``int4``): both reconstruct the same uniform
-  grid, so their MAE is identical; documented bound
+* affine (``lut4``): the uniform grid; documented bound
   ``MAE(ptq) <= PTQ_MAE_BOUND * MAE(ideal)``.
 * non-affine pair (``nf4`` vs the direct full-table NF4 dequant oracle):
   the least-squares D&C split plus the per-code residual correction
@@ -82,12 +81,13 @@ def train_one(mode, steps=300, lr=3e-2):
     return mae, params
 
 
-def ptq_mae(params, kernel="lut_dc", prune_threshold=None):
+def ptq_mae(params, quant="lut4"):
     """MAE of the bf16-trained net with weights frozen to 4-bit codes —
-    the serving engine's ``quant="lut4"|"int4"|"nf4"|"nf4p"`` transform."""
+    the serving engine's ``quant="lut4"|"nf4"|"nf4p"`` transform, or the
+    ``nf4_direct`` oracle."""
     x, y = make_data()
-    q1 = quantize_weight(params["w1"], kernel, prune_threshold)
-    q2 = quantize_weight(params["w2"], kernel, prune_threshold)
+    q1 = quantize_weight(params["w1"], quant)
+    q2 = quantize_weight(params["w2"], quant)
     h = jnp.tanh(quantized_matmul(x, q1) + params["b1"])
     out = quantized_matmul(h, q2) + params["b2"]
     return float(jnp.abs(out - y).mean())
@@ -112,12 +112,9 @@ def main():
         results[mode] = mae
         trained[mode] = params
         print(f"  {mode:>10}: MAE {mae:.4f}")
-    ptq = (("lut_dc", None, "ptq_lut4"), ("dequant", None, "ptq_int4"),
-           ("nf4_dc", None, "ptq_nf4"),
-           ("nf4_dequant", None, "ptq_nf4_direct"),
-           ("nf4_dc", NF4P_PRUNE_THRESHOLD, "ptq_nf4p"))
-    for kernel, prune, label in ptq:
-        results[label] = ptq_mae(trained["ideal"], kernel, prune)
+    for quant in ("lut4", "nf4", "nf4_direct", "nf4p"):
+        label = f"ptq_{quant}"
+        results[label] = ptq_mae(trained["ideal"], quant)
         print(f"  {label:>14}: MAE {results[label]:.4f}")
     tab = nf4p_table_report()
     print(f"  nf4p residual table: kept {tab['kept']}/16 entries, "
@@ -126,7 +123,6 @@ def main():
     assert results["ideal"] <= results["approx_dc"] * 1.2
     assert results["ptq_lut4"] <= results["ideal"] * PTQ_MAE_BOUND, \
         (results["ptq_lut4"], results["ideal"])
-    assert results["ptq_lut4"] == results["ptq_int4"]   # same affine grid
     # non-affine: residual-corrected D&C matches direct dequant up to
     # float rounding; pruning costs a bounded MAE delta and saves bytes
     assert abs(results["ptq_nf4"] - results["ptq_nf4_direct"]) \

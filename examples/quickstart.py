@@ -7,8 +7,8 @@
 4. a LunaDense-quantized transformer forward pass (model-level
    ``QuantConfig`` — dynamic quantization of every projection);
 5. the serving engine with ``EngineConfig(quant="lut4")`` — 4-bit decode
-   weights evaluated through the paper's D&C sub-table LUT gemm (the
-   ``--quant lut4`` flag on both serving CLIs).
+   weights through the LUT gemm, their table derived by the paper's D&C
+   split (the ``--quant lut4`` flag on both serving CLIs).
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """

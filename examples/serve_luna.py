@@ -8,9 +8,9 @@ LUNA mode.  This example also shows the v2 request lifecycle: one request
 is streamed token-by-token through its ``RequestHandle``.
 
 ``--quant`` is the shared flag registered by ``EngineConfig.add_cli_args``:
-``lut4``/``int4`` freeze 4-bit affine decode weights on the engine (the
-paper's D&C sub-table LUT gemm on the decode hot path), ``nf4``/``nf4p``
-freeze non-affine NF4 weights (D&C + full or pruned residual correction);
+``lut4`` freezes 4-bit affine decode weights on the engine (the LUT gemm
+on the decode hot path), ``nf4``/``nf4p`` freeze non-affine NF4 weights
+(D&C + full or pruned residual correction);
 any other spelling (``luna_*``, ``int8``, ``lut_nf4``, ``bf16``) is a
 model-level ``QuantConfig`` mode applied dynamically to every projection.
 

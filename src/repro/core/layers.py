@@ -90,12 +90,10 @@ def quant_matmul(x: jax.Array, w: jax.Array, cfg: QuantConfig | None,
 
     ``w`` may also be a frozen :class:`~repro.core.quant.QuantizedWeight`
     (the engine's ``EngineConfig(quant=...)`` decode path substitutes them
-    at construction); those route through the LUT GEMM selected by the
-    container's static ``kernel`` tag — the affine D&C sub-table sum
-    (``lut4``/``int4``) or the NF4 residual-corrected D&C / full-table
-    paths (``nf4``/``nf4p``) — regardless of ``cfg``: the model-level
-    ``cfg`` quantizes *dynamically* per call, engine-level quantization
-    froze the weight once.
+    at construction); those route through the LUT GEMM over the weight's
+    16-entry table (``lut4``, ``nf4`` or ``nf4p``) regardless of ``cfg``:
+    the model-level ``cfg`` quantizes *dynamically* per call, engine-level
+    quantization froze the weight once.
     """
     if isinstance(w, QuantizedWeight):
         from repro.kernels.lut_gemm import ops as lut_ops  # lazy: avoid cycle

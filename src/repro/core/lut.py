@@ -114,6 +114,38 @@ def prune_residual(residual: jax.Array, threshold: float
     return kept_idx, res[kept_idx]
 
 
+def dc_decompose_pruned(codebook: jax.Array, threshold: float,
+                        digit_bits: int = 2
+                        ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """D&C split of a LUT whose residual is pruned at ``threshold``.
+
+    The kept codes are those of :func:`prune_residual` on the full split's
+    residual, and they read their exact table value.  HI/LO are then the
+    least-squares fit over the codes that fall through to the pure
+    ``HI + LO`` sum only: fitting them to every code, kept ones included,
+    spends the split's freedom on values the residual corrects anyway
+    (NF4 at 0.05: worst fall-through error 0.047, a third of a code step,
+    against 0.0021 refit).  Returns ``(hi_tab, lo_tab, kept_idx,
+    kept_val)``; ``kept_val`` is each kept code's exact residual.
+    """
+    d = 1 << digit_bits
+    cb = jnp.asarray(codebook, jnp.float32)
+    _, _, residual = dc_decompose_codebook(cb, digit_bits)
+    kept_idx, _ = prune_residual(residual, threshold)
+    drop = np.ones(d * d, bool)
+    drop[np.asarray(kept_idx)] = False
+    q = np.arange(d * d)
+    design = np.zeros((d * d, 2 * d))
+    design[q, q >> digit_bits] = 1.0
+    design[q, d + (q & (d - 1))] = 1.0
+    fit = np.linalg.lstsq(design[drop], np.asarray(cb, np.float64)[drop],
+                          rcond=None)[0]
+    hi_tab = jnp.asarray(fit[:d], jnp.float32)
+    lo_tab = jnp.asarray(fit[d:], jnp.float32)
+    res = cb - (hi_tab[:, None] + lo_tab[None, :]).reshape(-1)
+    return hi_tab, lo_tab, kept_idx, res[kept_idx]
+
+
 def scatter_residual(kept_idx: jax.Array, kept_val: jax.Array,
                      size: int = 16) -> jax.Array:
     """Densify a pruned residual for evaluation: dropped codes read 0.

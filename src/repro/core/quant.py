@@ -18,14 +18,15 @@ which is exactly how the paper's Fig 13 NN-level MAE arises.
 
 Serving-side weight-only quantization (this module's second half) applies
 the same algebra statically: :class:`QuantizedWeight` freezes a projection
-into 4-bit codes + per-channel :class:`QParams` at engine construction, and
+into 4-bit codes, per-channel scale and zero point, and one 16-entry
+code-space table at engine construction, and
 :func:`quantize_decode_params` walks a model param tree replacing every
-decode-projection leaf.  The D&C sub-tables stored alongside the codes are
-the paper's Fig 2/3 decomposition of the 16-entry code LUT: a 4-bit code
-``q`` splits into 2-bit digits ``q = 4*q_hi + q_lo``, so the 16-entry table
-is evaluated as the sum of two 4-entry sub-tables (``HI[i] = 4i``,
-``LO[j] = j``) — 2 × (2**2 − 1) = 6 mux selects instead of 15, the select
-cost behind the paper's ~3.7× area saving.
+decode-projection leaf.  The table is derived through the paper's Fig 2/3
+decomposition of the 16-entry code LUT: a 4-bit code ``q`` splits into
+2-bit digits ``q = 4*q_hi + q_lo`` and the entry is the sum of two 4-entry
+sub-tables (``HI[i] = 4i``, ``LO[j] = j`` for the affine grid; a
+least-squares split plus a per-code residual for NF4) — folded once at
+the freeze, so the kernel reads one table.
 """
 from __future__ import annotations
 
@@ -142,20 +143,14 @@ ste_luna_matmul.defvjp(_ste_fwd, _ste_bwd)
 # Serving-side weight-only quantization: frozen 4-bit decode weights.
 # ---------------------------------------------------------------------------
 
-#: evaluation strategies for a frozen 4-bit weight (EngineConfig(quant=...)):
-#: "lut_dc" sums the paper's two 2-bit D&C sub-tables through the mux tree;
-#: "dequant" is the conventional-math baseline (direct affine dequant).
-#: Both reconstruct the identical affine grid — tokens match bit-for-bit.
-#: "nf4_dc" evaluates the NON-AFFINE NF4 codebook as HI + LO + a per-code
-#: residual correction (the least-squares D&C split of core.lut, possibly
-#: pruned); "nf4_dequant" is its conventional baseline (direct 16-entry
-#: codebook lookup — the oracle the residual path is pinned against).
-WEIGHT_KERNELS = ("lut_dc", "dequant", "nf4_dc", "nf4_dequant")
-
-#: default |residual| magnitude threshold for pruned sub-tables
-#: (quant="nf4p"): keeps exactly half the NF4 residual table's 16 entries
-#: — the capacity/accuracy operating point reported in the benches.
+#: |residual| magnitude threshold of the pruned NF4 table (quant="nf4p"):
+#: keeps exactly half the NF4 residual table's 16 entries.
 NF4P_PRUNE_THRESHOLD = 0.05
+
+#: the modes a weight freezes to: the engine's ``EngineConfig.quant``
+#: modes plus ``nf4_direct``, the full-table NF4 oracle that tests and the
+#: fig13 harness pin ``nf4`` against (not an engine mode).
+DECODE_QUANT_MODES = ("lut4", "nf4", "nf4p", "nf4_direct")
 
 
 @jax.tree_util.register_pytree_node_class
@@ -164,105 +159,105 @@ class QuantizedWeight:
     """A projection weight frozen to unsigned 4-bit codes (paper Sec. III).
 
     ``codes``: (..., K, N) int8 codes in [0, 16); ``scale``/``zero_point``:
-    (..., N) per-output-channel affine params from :func:`calibrate`;
-    ``hi_tab``/``lo_tab``: (..., 4) D&C sub-tables in code space
-    (``q = hi_tab[q >> 2] + lo_tab[q & 3]`` exactly for the affine kernels
-    — the Fig 2/3 split of the 16-entry LUT into two 4-entry tables).
-    ``residual``: ``None`` for affine kernels (the split is exact); for the
-    non-affine NF4 kernels a (..., 16) per-code correction table
-    (``cb[q] ~= hi_tab[q >> 2] + lo_tab[q & 3] + residual[q]``), dense or
-    pruned-to-zero below the magnitude threshold (see
-    :func:`repro.core.lut.prune_residual`).  ``kernel`` is static pytree
-    aux data selecting the evaluation strategy (see ``WEIGHT_KERNELS``).
+    (..., N) per-output-channel params; ``table``: (..., 16) f32 values in
+    code space.  The weight is ``(table[q] - zero_point) * scale``: every
+    mode is one 16-entry table read through the paper's mux tree (see
+    :func:`decode_table` for how each mode derives its table).
 
     Registered as a pytree so a stacked instance (leading layer axis on
     every array child) slices cleanly under ``jax.lax.scan`` and traces
-    through ``jax.jit`` like any other param leaf (a ``None`` residual is
-    an empty subtree, so affine instances flatten exactly as before).
+    through ``jax.jit`` like any other param leaf.
     """
     codes: jax.Array
     scale: jax.Array
     zero_point: jax.Array
-    hi_tab: jax.Array
-    lo_tab: jax.Array
-    residual: jax.Array | None = None
-    kernel: str = "lut_dc"
+    table: jax.Array
 
     def tree_flatten(self):
-        return ((self.codes, self.scale, self.zero_point,
-                 self.hi_tab, self.lo_tab, self.residual), self.kernel)
+        return (self.codes, self.scale, self.zero_point, self.table), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, kernel=aux)
-
-    @property
-    def qparams(self) -> QParams:
-        return QParams(self.scale, self.zero_point, 4)
+        return cls(*children)
 
 
-def _nf4_dc_tables(prune_threshold: float | None):
-    """(hi, lo, residual) least-squares D&C split of the NF4 codebook,
-    residual optionally pruned to the kept-set sparse gather (dropped
-    codes read 0 and fall through to the pure HI + LO sum)."""
+def decode_table(quant: str) -> jax.Array:
+    """The (16,) f32 code-space table a weight frozen at ``quant`` carries.
+
+    The affine and NF4 tables are the paper's D&C split (Figs 2/3) folded
+    once: ``(HI[q >> 2] + LO[q & 3]) + RES[q]``.
+
+    * ``lut4``: ``HI[i] = 4i``, ``LO[j] = j``, no residual — exactly ``q``.
+    * ``nf4``: the least-squares split of the NF4 codebook
+      (:func:`repro.core.lut.dc_decompose_codebook`) plus its full
+      per-code residual.
+    * ``nf4p``: the split refit with its residual pruned at
+      :data:`NF4P_PRUNE_THRESHOLD` (:func:`repro.core.lut.dc_decompose_pruned`):
+      dropped codes read the pure ``HI + LO`` sum.
+    * ``nf4_direct``: the NF4 codebook itself, the oracle.
+    """
     from repro.core.lut import (NF4_CODEBOOK, dc_decompose_codebook,
-                                prune_residual, scatter_residual)
-    hi_tab, lo_tab, residual = dc_decompose_codebook(jnp.asarray(NF4_CODEBOOK))
-    if prune_threshold is not None:
-        kept_idx, kept_val = prune_residual(residual, prune_threshold)
-        residual = scatter_residual(kept_idx, kept_val)
-    return hi_tab, lo_tab, residual
+                                dc_decompose_pruned, scatter_residual)
+    if quant not in DECODE_QUANT_MODES:
+        raise ValueError(f"unknown decode quant mode {quant!r}; "
+                         f"one of {DECODE_QUANT_MODES}")
+    # a one-off constant: evaluated now even when the freeze is traced
+    with jax.ensure_compile_time_eval():
+        cb = jnp.asarray(NF4_CODEBOOK)
+        if quant == "nf4_direct":
+            return cb
+        if quant == "lut4":
+            hi_tab = 4.0 * jnp.arange(4, dtype=jnp.float32)
+            lo_tab = jnp.arange(4, dtype=jnp.float32)
+            residual = jnp.zeros((16,), jnp.float32)
+        elif quant == "nf4":
+            hi_tab, lo_tab, residual = dc_decompose_codebook(cb)
+        else:
+            hi_tab, lo_tab, kept_idx, kept_val = dc_decompose_pruned(
+                cb, NF4P_PRUNE_THRESHOLD)
+            residual = scatter_residual(kept_idx, kept_val)
+        return (hi_tab[:, None] + lo_tab[None, :]).reshape(16) + residual
 
 
-def quantize_weight(w: jax.Array, kernel: str = "lut_dc",
-                    prune_threshold: float | None = None) -> QuantizedWeight:
+def quantize_weight(w: jax.Array, quant: str = "lut4") -> QuantizedWeight:
     """Freeze a (…, K, N) float weight to a :class:`QuantizedWeight`.
 
-    Affine kernels (``"lut_dc"`` / ``"dequant"``) calibrate per output
-    channel over the K axis (the paper's operands are unsigned codes; see
-    the module docstring identity) and carry the exact code-space split
-    ``HI[i] = 4i``, ``LO[j] = j`` with no residual.  The NF4 kernels
-    (``"nf4_dc"`` / ``"nf4_dequant"``) encode against the non-affine NF4
-    codebook with per-output-channel absmax scaling (the codebook is
-    symmetric on [-1, 1], so ``zero_point`` is 0) and carry the
-    least-squares D&C split of the codebook plus its per-code residual —
-    pruned below ``prune_threshold`` when given (``quant="nf4p"``).
+    ``lut4`` calibrates per output channel over the K axis (the paper's
+    operands are unsigned codes; see the module docstring identity).  The
+    NF4 modes encode against the non-affine NF4 codebook with
+    per-output-channel absmax scaling (the codebook is symmetric on
+    [-1, 1], so ``zero_point`` is 0).  The table is :func:`decode_table`.
 
     Leaves with extra leading axes (scan-stacked layers) are quantized
     one slice at a time and stacked, so every array child carries the same
     leading axes and the container remains ``jax.lax.scan``-sliceable.
     """
-    if kernel not in WEIGHT_KERNELS:
-        raise ValueError(f"unknown weight kernel {kernel!r}; "
-                         f"one of {WEIGHT_KERNELS}")
+    return _freeze(w, quant, decode_table(quant))
+
+
+def _freeze(w: jax.Array, quant: str, table: jax.Array) -> QuantizedWeight:
     if w.ndim > 2:
         # sequential on purpose: the NF4 encode's (K, N, 16) f32 distance
         # temporary exists for one slice only (a vmap over 38 full-width
         # Mamba2 w_in slices would need 42 GB), and each slice is bitwise
-        # the 2-D encode (a lax.map under jit moves scales by an ulp)
-        slices = [quantize_weight(w[i], kernel, prune_threshold)
+        # the 2-D encode (a lax.map under jit moves scales by an ulp).
+        # Each slice is waited for: dispatch is asynchronous, and slices
+        # queued ahead of the device would each hold their temporary.
+        slices = [jax.block_until_ready(_freeze(w[i], quant, table))
                   for i in range(w.shape[0])]
         return jax.tree.map(lambda *xs: jnp.stack(xs), *slices)
     wf = w.astype(jnp.float32)
-    if kernel in ("nf4_dc", "nf4_dequant"):
-        from repro.core.lut import NF4_CODEBOOK
-        cb = jnp.asarray(NF4_CODEBOOK)
-        scale = jnp.maximum(jnp.max(jnp.abs(wf), axis=0), 1e-8)
-        wn = wf / scale[None, :]
-        codes = jnp.argmin(jnp.abs(wn[..., None] - cb), axis=-1)
-        hi_tab, lo_tab, residual = _nf4_dc_tables(prune_threshold)
-        return QuantizedWeight(codes.astype(jnp.int8),
-                               scale.astype(jnp.float32),
-                               jnp.zeros_like(scale, jnp.float32),
-                               hi_tab, lo_tab, residual=residual,
-                               kernel=kernel)
-    qp = calibrate(wf, bits=4, axis=-1)
-    codes = quantize(wf, qp).astype(jnp.int8)
-    # D&C sub-tables (code space): q = HI[q>>2] + LO[q&3], HI[i]=4i, LO[j]=j.
-    hi_tab = (4.0 * jnp.arange(4, dtype=jnp.float32))
-    lo_tab = jnp.arange(4, dtype=jnp.float32)
-    return QuantizedWeight(codes, qp.scale, qp.zero_point,
-                           hi_tab, lo_tab, kernel=kernel)
+    if quant == "lut4":
+        qp = calibrate(wf, bits=4, axis=-1)
+        return QuantizedWeight(quantize(wf, qp).astype(jnp.int8), qp.scale,
+                               qp.zero_point, table)
+    from repro.core.lut import NF4_CODEBOOK
+    cb = jnp.asarray(NF4_CODEBOOK)
+    scale = jnp.maximum(jnp.max(jnp.abs(wf), axis=0), 1e-8)
+    wn = wf / scale[None, :]
+    codes = jnp.argmin(jnp.abs(wn[..., None] - cb), axis=-1)
+    return QuantizedWeight(codes.astype(jnp.int8), scale.astype(jnp.float32),
+                           jnp.zeros_like(scale, jnp.float32), table)
 
 
 #: decode-projection leaf names eligible for engine-level quantization.
@@ -285,33 +280,18 @@ DECODE_QUANT_TARGETS = frozenset({
 _QUANT_PARENT_KEYS = frozenset({"attn", "mlp", "m", "shared", "moe"})
 
 
-#: EngineConfig(quant=...) mode -> (weight kernel, residual prune threshold).
-#: ``nf4_direct`` is not an engine mode: it is the conventional full-table
-#: NF4 dequant oracle the residual-corrected ``nf4`` path is pinned against
-#: in tests and the fig13 harness.
-DECODE_QUANT_KERNELS = {
-    "lut4": ("lut_dc", None),
-    "int4": ("dequant", None),
-    "nf4": ("nf4_dc", None),
-    "nf4p": ("nf4_dc", NF4P_PRUNE_THRESHOLD),
-    "nf4_direct": ("nf4_dequant", None),
-}
-
-
 def quantize_decode_params(params, quant: str):
     """Walk a model param tree, freezing every decode projection to 4-bit.
 
-    ``quant``: ``"lut4"`` (affine D&C sub-table LUT evaluation), ``"int4"``
-    (direct-dequant baseline), ``"nf4"`` (non-affine NF4 codebook, D&C
-    sub-tables + per-code residual correction), ``"nf4p"`` (same with the
-    residual pruned below ``NF4P_PRUNE_THRESHOLD``), or ``"nf4_direct"``
-    (full-table NF4 dequant — the test oracle, not an engine mode).  A
-    leaf is quantized iff its dict key is in ``DECODE_QUANT_TARGETS``, some
-    ancestor key is in the quant-parent set, and it is a float matrix —
-    everything else (norms, embeddings, routers, MLA w_uk/w_uv) passes
-    through untouched.
+    ``quant``: one of :data:`DECODE_QUANT_MODES` (``"lut4"`` affine,
+    ``"nf4"``/``"nf4p"`` NF4 with the full or pruned residual,
+    ``"nf4_direct"`` the test oracle); :func:`decode_table` says what
+    each freezes to.  A leaf is quantized iff its dict key is in
+    ``DECODE_QUANT_TARGETS``, some ancestor key is in the quant-parent
+    set, and it is a float matrix — everything else (norms, embeddings,
+    routers, MLA w_uk/w_uv) passes through untouched.
     """
-    kernel, prune = DECODE_QUANT_KERNELS[quant]
+    table = decode_table(quant)
 
     def walk(node, path):
         if isinstance(node, dict):
@@ -323,7 +303,7 @@ def quantize_decode_params(params, quant: str):
                 and any(p in _QUANT_PARENT_KEYS for p in path[:-1])
                 and hasattr(node, "ndim") and node.ndim >= 2
                 and jnp.issubdtype(node.dtype, jnp.floating)):
-            return quantize_weight(node, kernel, prune)
+            return _freeze(node, quant, table)
         return node
 
     return walk(params, ())

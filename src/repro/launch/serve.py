@@ -21,7 +21,7 @@
 Engine knobs are single-sourced in ``repro.serve.config.EngineConfig`` —
 ``EngineConfig.add_cli_args`` registers the flags (including the shared
 ``--quant``), ``from_args`` builds the validated config.  ``--quant
-lut4|int4|nf4|nf4p`` freezes 4-bit decode weights on the engine (affine
+lut4|nf4|nf4p`` freezes 4-bit decode weights on the engine (affine
 grid or NF4 codebook with full/pruned residual correction — see
 docs/quantization.md); any other spelling (bf16, int8, luna_*, ...) is a
 model-level mode applied to every projection dynamically.

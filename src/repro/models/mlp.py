@@ -3,7 +3,7 @@
 Every projection (``w_up``/``w_gate``/``w_down``) routes through
 ``core.layers.quant_matmul``, so these leaves participate in BOTH
 quantization surfaces: model-level ``QuantConfig`` (dynamic, every call)
-and engine-level ``EngineConfig(quant="lut4"|"int4")``, where the serving
+and engine-level ``EngineConfig(quant="lut4"|"nf4"|"nf4p")``, where the serving
 backend freezes them to 4-bit ``QuantizedWeight`` containers for the
 decode hot path (prefill keeps the float tree).
 """

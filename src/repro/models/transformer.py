@@ -267,7 +267,7 @@ class TransformerLM:
         ``params`` may be the engine's frozen 4-bit decode tree
         (``EngineConfig(quant=...)``): attention/MLP projection leaves are
         then ``QuantizedWeight`` containers that ``quant_matmul`` routes
-        through the D&C LUT gemm — scan-stacked leaves slice per layer
+        through the LUT gemm — scan-stacked leaves slice per layer
         like any float leaf (registered pytree with a leading L axis).
 
         ``routes`` (MoE): also return the routed experts of every MoE

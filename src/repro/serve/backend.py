@@ -168,16 +168,15 @@ class CacheBackend:
         """Freeze the decode-step weight tree once at construction.
 
         ``quant=None`` keeps the caller's tree untouched (decode params ARE
-        the prefill params — the token-identity guarantee).  ``"lut4"`` /
-        ``"int4"`` replace every decode-projection leaf with a 4-bit
+        the prefill params — the token-identity guarantee).  ``"lut4"``
+        replaces every decode-projection leaf with a 4-bit
         :class:`~repro.core.quant.QuantizedWeight` on the exact affine
-        grid (D&C sub-table LUT vs direct-dequant evaluation);
-        ``"nf4"`` / ``"nf4p"`` freeze the same leaves against the
-        non-affine NF4 codebook, carrying the least-squares D&C split plus
-        its per-code residual (full, or pruned below the magnitude
-        threshold).  The quantized tree is backend-owned state, like the
-        cache slab: prefill always runs the full-precision tree, only the
-        decode hot path reads this one.
+        grid; ``"nf4"`` / ``"nf4p"`` freeze the same leaves against the
+        non-affine NF4 codebook, whose table folds the least-squares D&C
+        split with its per-code residual (full, or pruned below the
+        magnitude threshold).  The quantized tree is backend-owned state,
+        like the cache slab: prefill always runs the full-precision tree,
+        only the decode hot path reads this one.
         """
         if quant is None:
             self.decode_params = params

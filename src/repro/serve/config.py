@@ -27,12 +27,11 @@ from repro.serve.sampling import SamplingConfig
 
 #: engine-level decode quantization modes (EngineConfig.quant); model-level
 #: modes (bf16/int8/luna_* — dynamic per-call quantization via QuantConfig)
-#: stay on the model config and share the ``--quant`` CLI flag.  The affine
-#: pair (lut4/int4) is token-identical by construction; the non-affine pair
-#: (nf4/nf4p) evaluates the NF4 codebook through the least-squares D&C
+#: stay on the model config and share the ``--quant`` CLI flag.  lut4 is
+#: the affine grid; nf4/nf4p evaluate the NF4 codebook through the D&C
 #: split with a per-code residual correction — full for nf4, pruned below
 #: a magnitude threshold for nf4p (table capacity vs bounded accuracy).
-ENGINE_QUANT_MODES = ("lut4", "int4", "nf4", "nf4p")
+ENGINE_QUANT_MODES = ("lut4", "nf4", "nf4p")
 
 #: speculative-decoding draft proposers (EngineConfig.spec).  "ngram" is
 #: prompt-lookup drafting (no extra weights: the longest context-suffix
@@ -69,15 +68,13 @@ class EngineConfig:
       active work before re-checking (a ``submit()``/``cancel()`` wakes it
       immediately, so this only bounds shutdown latency and idle spin).
     * ``quant`` — decode weight quantization: ``"lut4"`` freezes decode
-      projections to 4-bit codes evaluated through the paper's D&C
-      sub-table LUT GEMM, ``"int4"`` is the direct-dequant baseline
-      (token-identical math, conventional evaluation), ``"nf4"`` encodes
-      against the non-affine NF4 codebook and evaluates it as the
+      projections to 4-bit codes on the affine grid, ``"nf4"`` encodes
+      against the non-affine NF4 codebook, whose table is the
       least-squares D&C split plus a per-code residual correction,
       ``"nf4p"`` prunes that residual below a magnitude threshold (smaller
-      tables, bounded accuracy cost), ``None`` keeps bf16 decode
-      token-identical to prior releases.  Prefill always runs full
-      precision; see ``docs/quantization.md``.
+      tables, bounded accuracy cost); every mode runs the one LUT GEMM.
+      ``None`` keeps bf16 decode token-identical to prior releases.
+      Prefill always runs full precision; see ``docs/quantization.md``.
     * ``trace`` / ``trace_buffer`` — request-lifecycle tracing
       (``repro.obs``): record clock-stamped span events into a ring
       buffer of ``trace_buffer`` events, exportable as Perfetto JSON,
@@ -248,9 +245,8 @@ class EngineConfig:
         ap.add_argument("--top-k", type=int, default=40)
         ap.add_argument("--seed", type=int, default=0)
         ap.add_argument("--quant", default=None,
-                        help="weight quantization: 'lut4' (4-bit decode "
-                             "weights through the D&C sub-table LUT gemm), "
-                             "'int4' (direct-dequant baseline), 'nf4' "
+                        help="weight quantization: 'lut4' (4-bit affine "
+                             "decode weights through the LUT gemm), 'nf4' "
                              "(non-affine NF4 codebook, D&C + residual "
                              "correction) or 'nf4p' (pruned residual sub-"
                              "table) quantize the DECODE hot path at "

@@ -627,8 +627,8 @@ class Engine:
                              annotate=jax.profiler.TraceAnnotation)
         # decode weights are backend-owned state: the full-precision tree
         # itself under quant=None (token-identity), a frozen 4-bit tree
-        # under quant="lut4"/"int4" (affine) or "nf4"/"nf4p" (NF4 codebook
-        # + D&C residual correction) — prefill always uses self.params.
+        # under quant="lut4" (affine) or "nf4"/"nf4p" (NF4 codebook + D&C
+        # residual correction) — prefill always uses self.params.
         # Traced, the freeze span ends once the tree is on the device.
         with self.tracer.span("freeze", quant=config.quant or "bf16"):
             self.decode_params = self.backend.prepare_decode_params(

@@ -9,13 +9,13 @@ hypothesis = pytest.importorskip(
 from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
-from repro.core.lut import NF4_CODEBOOK
+from repro.core.quant import quantize_weight
 from repro.kernels.flash_attention.ops import mha
 from repro.kernels.luna_mm.ops import luna_matmul_f32_kernel, luna_mm_codes
 from repro.kernels.luna_mm.ref import luna_mm_ref
-from repro.kernels.lut_gemm.lut_gemm import lut_gemm
-from repro.kernels.lut_gemm.ops import codebook_quantize, nf4_matmul_kernel
-from repro.kernels.lut_gemm.ref import lut_gemm_ref
+from repro.kernels.lut_gemm.lut_gemm import lut_gemm_dc_res
+from repro.kernels.lut_gemm.ops import lut_matmul
+from repro.kernels.lut_gemm.ref import lut_gemm_dc_res_ref
 
 MODES = ["conventional", "dc", "opt_dc", "approx_dc", "approx_dc2"]
 
@@ -86,27 +86,38 @@ def test_luna_mm_approx_halves_matmul_work():
                                    (128, 128, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lut_gemm_shapes_dtypes(shape, dtype):
+    """An NF4 weight through the LUT kernel at the tiles ``lut_matmul``
+    picks (ragged M and N, K below one block) against the reference; an
+    ``x`` given in its own dtype is read as the same f32 values and the
+    f32 result rounded once to that dtype."""
     m, k, n = shape
     rng = np.random.default_rng(hash(shape) % 2**32)
     x = jnp.asarray(rng.normal(size=(m, k)), dtype)
     w = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
-    got = nf4_matmul_kernel(x.astype(jnp.float32), w, interpret=True)
-    cb = jnp.asarray(NF4_CODEBOOK)
-    codes, scale = codebook_quantize(w, cb)
-    ref = lut_gemm_ref(x.astype(jnp.float32), codes, cb, scale)
+    qw = quantize_weight(w, "nf4")
+    got = lut_matmul(x.astype(jnp.float32), qw, interpret=True)
+    ref = lut_gemm_dc_res_ref(x.astype(jnp.float32), qw.codes, qw.table,
+                              qw.zero_point, qw.scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+    got_x = lut_matmul(x, qw, interpret=True)
+    assert got_x.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got_x),
+                                  np.asarray(got.astype(dtype)))
 
 
 def test_lut_gemm_arbitrary_codebook():
-    """Programmability: any 16-entry table, not just NF4/uniform."""
+    """Programmability: any 16-entry table, not just NF4/uniform, is the
+    kernel's own input (with any per-channel zero point)."""
     rng = np.random.default_rng(3)
     cb = jnp.asarray(np.sort(rng.normal(size=16)).astype(np.float32))
     codes = jnp.asarray(rng.integers(0, 16, (64, 32)), jnp.int8)
+    zp = jnp.asarray(rng.normal(size=32), jnp.float32)
     scale = jnp.asarray(rng.uniform(0.5, 2.0, 32), jnp.float32)
     x = jnp.asarray(rng.normal(size=(16, 64)), jnp.float32)
-    got = lut_gemm(x, codes, cb, scale, bm=16, bn=32, bk=64, interpret=True)
-    ref = lut_gemm_ref(x, codes, cb, scale)
+    got = lut_gemm_dc_res(x, codes, cb, zp, scale, bm=16, bn=32, bk=64,
+                          interpret=True)
+    ref = lut_gemm_dc_res_ref(x, codes, cb, zp, scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
 

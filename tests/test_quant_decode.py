@@ -3,14 +3,15 @@
 The load-bearing pins:
   * quant=None is token-identical to the pre-quant engine (the decode tree
     IS the prefill tree — same object);
-  * the D&C Pallas kernel, its jnp ref, and the engine's jnp decode path
-    agree bit-for-bit on the same frozen weights;
-  * quant="lut4" and quant="int4" emit identical tokens (two evaluation
-    strategies of one affine grid — the paper's D&C argument);
+  * every mode freezes to one 16-entry table, bitwise the D&C fold
+    ``(HI[q>>2] + LO[q&3]) + RES[q]`` (or the codebook, for the oracle),
+    and the jnp decode path's weight is bitwise that table's;
+  * the LUT Pallas kernel (the path every frozen weight takes on the
+    TPU), its jnp ref, and the engine's jnp decode path agree to f32
+    rounding on the same frozen weights;
   * quant="nf4" (non-affine: least-squares D&C + per-code residual
     correction) emits tokens identical to the direct full-table NF4
-    dequant oracle; its Pallas kernel, the path it takes on the TPU,
-    agrees with the jnp ref and the jnp decode path to f32 rounding;
+    dequant oracle;
   * quant="nf4p" (pruned residual sub-table) saves table bytes and stays
     above the documented token-agreement threshold vs unpruned nf4;
   * dc_decompose_codebook is least-squares-optimal (property test);
@@ -28,14 +29,15 @@ import numpy as np
 import pytest
 
 from repro.core.lut import (NF4_CODEBOOK, dc_decompose_codebook,
-                            prune_residual, residual_table_bytes,
-                            scatter_residual)
-from repro.core.quant import (NF4P_PRUNE_THRESHOLD, QuantizedWeight,
-                              quantize_decode_params, quantize_weight)
+                            dc_decompose_pruned, prune_residual,
+                            residual_table_bytes, scatter_residual)
+from repro.core.quant import (DECODE_QUANT_MODES, NF4P_PRUNE_THRESHOLD,
+                              QuantizedWeight, quantize_decode_params,
+                              quantize_weight)
 from repro.kernels.lut_gemm import ops as lut_ops
 from repro.kernels.lut_gemm.lut_gemm import lut_gemm_dc_res
-from repro.kernels.lut_gemm.ops import lut4_matmul_kernel, quantized_matmul
-from repro.kernels.lut_gemm.ref import lut_gemm_dc_ref, lut_gemm_dc_res_ref
+from repro.kernels.lut_gemm.ops import quantized_matmul
+from repro.kernels.lut_gemm.ref import lut_gemm_dc_res_ref
 from repro.models.registry import get_config, get_model
 from repro.serve.config import EngineConfig
 from repro.serve.engine import Engine, Request
@@ -86,19 +88,69 @@ def test_quant_none_is_token_identical_and_aliases_params():
 # ---------------------------------------------------------------------------
 
 def test_lut_gemm_dc_pallas_matches_ref_and_jnp_path():
-    """The D&C Pallas kernel (interpret), the jnp oracle, and the engine's
-    decode-path matmul agree on identical frozen weights."""
+    """An affine (lut4) weight through the LUT Pallas kernel (interpret),
+    the jnp oracle, and the engine's decode-path matmul: the kernel and
+    the oracle agree to f32 rounding, and the jnp path is bitwise the
+    affine weight ``(q - zp) * s`` it folds the scale into first."""
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(8, 64)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(64, 48)), jnp.float32)
-    qw = quantize_weight(w, "lut_dc")
-    ref = lut_gemm_dc_ref(x, qw.codes, qw.hi_tab, qw.lo_tab,
-                          qw.zero_point, qw.scale)
-    pallas = lut4_matmul_kernel(x, w, interpret=True)
+    qw = quantize_weight(w, "lut4")
+    ref = lut_gemm_dc_res_ref(x, qw.codes, qw.table, qw.zero_point,
+                              qw.scale)
+    pallas = lut_ops.lut_matmul(x, qw, interpret=True)
     jnp_path = quantized_matmul(x, qw)
     np.testing.assert_allclose(np.asarray(pallas), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(jnp_path), np.asarray(ref))
+    np.testing.assert_allclose(np.asarray(jnp_path), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    w_hat = ((qw.codes.astype(jnp.float32) - qw.zero_point[None, :])
+             * qw.scale[None, :])
+    np.testing.assert_array_equal(np.asarray(jnp_path), np.asarray(x @ w_hat))
+
+
+def _parent_table(quant):
+    """Each mode's table restated as the per-element evaluation the
+    weight kernels made before the table was folded at the freeze:
+    ``(HI[q>>2] + LO[q&3]) + RES[q]`` (``q`` itself for the affine grid)
+    or the NF4 codebook for the direct oracle."""
+    q = jnp.arange(16)
+    if quant == "lut4":
+        return q.astype(jnp.float32)
+    cb = jnp.asarray(NF4_CODEBOOK)
+    if quant == "nf4_direct":
+        return cb
+    if quant == "nf4":
+        hi, lo, res = dc_decompose_codebook(cb)
+    else:
+        hi, lo, kept_idx, kept_val = dc_decompose_pruned(
+            cb, NF4P_PRUNE_THRESHOLD)
+        res = scatter_residual(kept_idx, kept_val)
+    return (hi[q >> 2] + lo[q & 3]) + res[q]
+
+
+@pytest.mark.parametrize("quant", DECODE_QUANT_MODES)
+def test_frozen_table_is_the_dc_fold(quant):
+    """The one table a frozen weight carries is bitwise the per-element
+    evaluation of its mode, and the jnp decode path's weight is bitwise
+    ``(table[q] - zp) * s`` — for the affine grid also the direct
+    dequant ``(q - zp) * s``, for the oracle ``codebook[q] * s``."""
+    rng = np.random.default_rng(9)
+    w = jnp.asarray(rng.normal(size=(64, 24)), jnp.float32)
+    qw = quantize_weight(w, quant)
+    want = _parent_table(quant)
+    assert qw.table.dtype == jnp.float32 and qw.table.shape == (16,)
+    np.testing.assert_array_equal(np.asarray(qw.table), np.asarray(want))
+    q = qw.codes.astype(jnp.int32)
+    if quant == "lut4":
+        w_parent = (q.astype(jnp.float32) - qw.zero_point) * qw.scale
+    elif quant == "nf4_direct":
+        w_parent = jnp.asarray(NF4_CODEBOOK)[q] * qw.scale
+    else:
+        assert not np.any(np.asarray(qw.zero_point))
+        w_parent = (want[q] - qw.zero_point) * qw.scale
+    got = quantized_matmul(jnp.eye(64, dtype=jnp.float32), qw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(w_parent))
 
 
 def test_dc_decomposition_exact_for_affine_free_for_nf4():
@@ -116,24 +168,23 @@ def test_dc_decomposition_exact_for_affine_free_for_nf4():
 
 
 def test_nf4_dc_res_pallas_bitwise_equals_ref():
-    """On the SAME frozen tables (quantize once, eagerly — the engine's
-    freeze-at-construction discipline) the residual-corrected D&C Pallas
-    kernel and its jnp ref agree to f32 rounding at every tiling.  They
-    build the same weights (one folded 16-entry table gives the values of
-    the 6-select sum plus the residual gather bit for bit) and apply the
+    """On the SAME frozen NF4 weight (quantize once, eagerly — the
+    engine's freeze-at-construction discipline) the LUT Pallas kernel and
+    its jnp ref agree to f32 rounding at every tiling.  They build the
+    same weights (one 16-entry table, folded at the freeze) and apply the
     zero-point and scale in the same places; only the dot's summation
     order differs: a (K, bn) block's dot sums in another order than the
     whole (K, N) dot on XLA's CPU backend (up to 5.7e-6 apart at bn=8)."""
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(size=(8, 64)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(64, 48)), jnp.float32)
-    qw = quantize_weight(w, "nf4_dc")
-    ref = lut_gemm_dc_res_ref(x, qw.codes, qw.hi_tab, qw.lo_tab,
-                              qw.residual, qw.zero_point, qw.scale)
+    qw = quantize_weight(w, "nf4")
+    ref = lut_gemm_dc_res_ref(x, qw.codes, qw.table, qw.zero_point,
+                              qw.scale)
     for bn, bk in ((8, 64), (16, 32), (48, 64), (32, 16)):
-        pallas = lut_gemm_dc_res(x, qw.codes, qw.hi_tab, qw.lo_tab,
-                                 qw.residual, qw.zero_point, qw.scale,
-                                 bm=8, bn=bn, bk=bk, interpret=True)
+        pallas = lut_gemm_dc_res(x, qw.codes, qw.table, qw.zero_point,
+                                 qw.scale, bm=8, bn=bn, bk=bk,
+                                 interpret=True)
         np.testing.assert_allclose(np.asarray(pallas), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5,
                                    err_msg=f"bn={bn} bk={bk}")
@@ -148,17 +199,17 @@ def _scan_quantized(x, qs, matmul):
     return jax.lax.scan(lambda c, qwi: (c, matmul(x, qwi)), 0, qs)[1]
 
 
-@pytest.mark.parametrize("x_shape,k,n,prune,stacked", [
-    ((12, 256), 256, 200, None, False),       # M = 12, ragged N edge block
-    ((1, 256), 256, 200, None, False),        # M = 1
-    ((12, 1, 256), 256, 200, None, False),    # (B, 1, K) decode input
-    ((3, 2, 256), 256, 96, None, False),      # (B, W, K) verify window
-    ((12, 256), 256, 200, NF4P_PRUNE_THRESHOLD, False),   # nf4p residual
-    ((12, 256), 256, 200, None, True),        # sliced under lax.scan
+@pytest.mark.parametrize("x_shape,k,n,quant,stacked", [
+    ((12, 256), 256, 200, "nf4", False),      # M = 12, ragged N edge block
+    ((1, 256), 256, 200, "nf4", False),       # M = 1
+    ((12, 1, 256), 256, 200, "nf4", False),   # (B, 1, K) decode input
+    ((3, 2, 256), 256, 96, "nf4", False),     # (B, W, K) verify window
+    ((12, 256), 256, 200, "nf4p", False),     # nf4p residual
+    ((12, 256), 256, 200, "nf4", True),       # sliced under lax.scan
 ], ids=["m12", "m1", "b1k", "bwk", "nf4p", "scan"])
 def test_nf4_dc_tpu_route_matches_jnp_path(monkeypatch, x_shape, k, n,
-                                            prune, stacked):
-    """The TPU route of ``quantized_matmul`` (``nf4_dc_matmul``, here in
+                                            quant, stacked):
+    """The TPU route of ``quantized_matmul`` (``lut_matmul``, here in
     interpret mode) against its jnp path, the reference on every other
     platform: equal to f32 rounding.  Code blocks are capped at 128
     columns so that N = 200 ends in a masked, ragged block."""
@@ -167,8 +218,8 @@ def test_nf4_dc_tpu_route_matches_jnp_path(monkeypatch, x_shape, k, n,
     x = jnp.asarray(rng.normal(size=x_shape), jnp.float32)
     w_shape = (3, k, n) if stacked else (k, n)
     w = jnp.asarray(rng.normal(size=w_shape) * 0.05, jnp.float32)
-    qw = quantize_weight(w, "nf4_dc", prune)
-    kernel = functools.partial(lut_ops.nf4_dc_matmul, interpret=True)
+    qw = quantize_weight(w, quant)
+    kernel = functools.partial(lut_ops.lut_matmul, interpret=True)
     if stacked:
         got = _scan_quantized(x, qw, kernel)
         want = _scan_quantized(x, qw, quantized_matmul)
@@ -188,8 +239,8 @@ def test_nf4_dc_expert_route_matches_jnp_path(m):
     rng = np.random.default_rng(8)
     x = jnp.asarray(rng.normal(size=(3, m, 384)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 384, 200)) * 0.05, jnp.float32)
-    qw = quantize_weight(w, "nf4_dc")
-    got = lut_ops.nf4_dc_expert_matmul(x, qw, interpret=True)
+    qw = quantize_weight(w, "nf4")
+    got = lut_ops.lut_expert_matmul(x, qw, interpret=True)
     want = lut_ops.quantized_expert_matmul(x, qw)
     per_expert = jnp.stack([
         quantized_matmul(x[i], jax.tree.map(lambda a: a[i], qw))
@@ -202,17 +253,16 @@ def test_nf4_dc_expert_route_matches_jnp_path(m):
 
 def test_nf4_dc_matches_direct_dequant_weights():
     """Residual-corrected D&C reconstructs the NF4 codebook exactly up to
-    float rounding: the nf4_dc and nf4_dequant kernels produce the same
+    float rounding: nf4 and the nf4_direct oracle produce the same
     effective weights (and the pruned variant's error is bounded)."""
     rng = np.random.default_rng(6)
     w = jnp.asarray(rng.normal(size=(96, 32)), jnp.float32)
     eye = jnp.eye(96, dtype=jnp.float32)
-    w_dc = quantized_matmul(eye, quantize_weight(w, "nf4_dc"))
-    w_direct = quantized_matmul(eye, quantize_weight(w, "nf4_dequant"))
+    w_dc = quantized_matmul(eye, quantize_weight(w, "nf4"))
+    w_direct = quantized_matmul(eye, quantize_weight(w, "nf4_direct"))
     np.testing.assert_allclose(np.asarray(w_dc), np.asarray(w_direct),
                                rtol=1e-5, atol=1e-5)
-    w_p = quantized_matmul(
-        eye, quantize_weight(w, "nf4_dc", NF4P_PRUNE_THRESHOLD))
+    w_p = quantized_matmul(eye, quantize_weight(w, "nf4p"))
     mae = float(jnp.abs(w_p - w_dc).mean())
     assert 0 < mae < 0.05, mae   # pruning costs something, but bounded
 
@@ -223,9 +273,9 @@ def test_quantized_weight_slices_under_scan():
     rng = np.random.default_rng(4)
     ws = jnp.asarray(rng.normal(size=(3, 32, 16)), jnp.float32)
     x = jnp.asarray(rng.normal(size=(2, 32)), jnp.float32)
-    qs = quantize_weight(ws, "lut_dc")
+    qs = quantize_weight(ws, "lut4")
     assert qs.codes.shape == (3, 32, 16) and qs.scale.shape == (3, 16)
-    assert qs.hi_tab.shape == (3, 4)
+    assert qs.table.shape == (3, 16)
 
     def body(c, qwi):
         return c, quantized_matmul(x, qwi)
@@ -237,23 +287,18 @@ def test_quantized_weight_slices_under_scan():
     np.testing.assert_array_equal(np.asarray(ys), np.asarray(per_layer))
 
 
-@pytest.mark.parametrize("kernel", ["lut_dc", "nf4_dc"])
-def test_stacked_encode_equals_per_slice_encode(kernel):
+@pytest.mark.parametrize("quant", ["lut4", "nf4"])
+def test_stacked_encode_equals_per_slice_encode(quant):
     """A stacked (L, K, N) leaf encodes slice by slice: every child is
     bitwise the stack of the 2-D encode of each slice, and of the
     whole-stack vmap encode it replaced."""
     rng = np.random.default_rng(5)
     ws = jnp.asarray(rng.normal(size=(4, 48, 40)), jnp.float32)
-    qs = quantize_weight(ws, kernel)
-    per_slice = [quantize_weight(ws[i], kernel) for i in range(4)]
-    vmapped = jax.vmap(lambda wi: quantize_weight(wi, kernel))(ws)
-    assert qs.kernel == kernel
-    for name in ("codes", "scale", "zero_point", "hi_tab", "lo_tab",
-                 "residual"):
+    qs = quantize_weight(ws, quant)
+    per_slice = [quantize_weight(ws[i], quant) for i in range(4)]
+    vmapped = jax.vmap(lambda wi: quantize_weight(wi, quant))(ws)
+    for name in ("codes", "scale", "zero_point", "table"):
         got = getattr(qs, name)
-        if got is None:
-            assert kernel == "lut_dc" and per_slice[0].residual is None
-            continue
         want = np.stack([np.asarray(getattr(q, name)) for q in per_slice])
         assert got.dtype == want.dtype, name
         np.testing.assert_array_equal(np.asarray(got), want, err_msg=name)
@@ -265,16 +310,6 @@ def test_stacked_encode_equals_per_slice_encode(kernel):
 # ---------------------------------------------------------------------------
 # engine behavior under quant
 # ---------------------------------------------------------------------------
-
-def test_lut4_and_int4_tokens_identical():
-    """Two evaluation strategies of the same affine grid: the D&C
-    sub-table LUT and direct dequant must emit identical tokens."""
-    cfg, params = _setup()
-    prompts = _prompts(cfg)
-    lut, _ = _serve(cfg, params, prompts, quant="lut4")
-    i4, _ = _serve(cfg, params, prompts, quant="int4")
-    assert lut == i4
-
 
 def test_quantized_greedy_agreement_above_threshold():
     """Accuracy bound on served tokens: prefill is full precision so every
@@ -334,17 +369,21 @@ def test_nf4p_pruned_decode_saves_bytes_within_agreement():
     assert 0 < int(kept_idx.shape[0]) < 16
     dense, pruned = residual_table_bytes(int(kept_idx.shape[0]))
     assert pruned < dense
-    # scatter rebuilds the pruned table the engine actually decodes with
+    # the engine decodes with the (HI + LO) + scatter_residual(kept) fold
+    # of the pruned split, whose kept codes are the ones counted above
     leaf = jax.tree.leaves(
         eng.decode_params,
         is_leaf=lambda x: isinstance(x, QuantizedWeight))
     qws = [x for x in leaf if isinstance(x, QuantizedWeight)]
-    assert qws and all(q.kernel == "nf4_dc" for q in qws)
-    want = scatter_residual(kept_idx, kept_val)
-    got = qws[0].residual
-    np.testing.assert_allclose(
-        np.asarray(got).reshape(-1, 16)[0], np.asarray(want),
-        rtol=1e-6, atol=1e-6)
+    hi, lo, p_idx, p_val = dc_decompose_pruned(
+        jnp.asarray(NF4_CODEBOOK), NF4P_PRUNE_THRESHOLD)
+    np.testing.assert_array_equal(np.asarray(p_idx), np.asarray(kept_idx))
+    want = ((hi[:, None] + lo[None, :]).reshape(16)
+            + scatter_residual(p_idx, p_val))
+    assert qws
+    tables = np.concatenate([np.asarray(q.table).reshape(-1, 16)
+                             for q in qws])
+    assert (tables == np.asarray(want)).all()
 
 
 # ---------------------------------------------------------------------------
@@ -428,17 +467,15 @@ else:
 def test_fig13_ptq_within_documented_bound():
     """The documented accuracy bound: the bf16-trained fig13 harness MLP,
     frozen to 4-bit QuantizedWeight leaves, stays within PTQ_MAE_BOUND of
-    its own MAE — and both evaluation kernels land the same number."""
+    its own MAE."""
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "examples", "fig13_nn_accuracy.py")
     spec = importlib.util.spec_from_file_location("fig13", path)
     fig13 = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(fig13)
     mae_ideal, trained = fig13.train_one("ideal")
-    mae_lut = fig13.ptq_mae(trained, "lut_dc")
-    mae_int = fig13.ptq_mae(trained, "dequant")
+    mae_lut = fig13.ptq_mae(trained, "lut4")
     assert mae_lut <= mae_ideal * fig13.PTQ_MAE_BOUND, (mae_lut, mae_ideal)
-    assert mae_lut == mae_int
 
 
 def test_quant_composes_with_paged_and_prefix_cache():
@@ -506,11 +543,13 @@ def test_mla_direct_use_leaves_stay_float():
 def test_engine_config_quant_validation():
     with pytest.raises(ValueError, match="quant"):
         EngineConfig(quant="fp3")
-    for mode in ("lut4", "int4", "nf4", "nf4p"):
+    for mode in ("lut4", "nf4", "nf4p"):
         assert EngineConfig(quant=mode).quant == mode
-    # "nf4_direct" is the test/fig13 oracle spelling, not an engine mode
-    with pytest.raises(ValueError, match="quant"):
-        EngineConfig(quant="nf4_direct")
+    # "nf4_direct" is the test/fig13 oracle spelling, not an engine mode,
+    # and "int4" (the old direct-dequant alias of lut4) is no mode at all
+    for mode in ("nf4_direct", "int4"):
+        with pytest.raises(ValueError, match="quant"):
+            EngineConfig(quant=mode)
     assert EngineConfig().quant is None
 
 
@@ -539,5 +578,11 @@ def test_from_args_routes_shared_quant_flag():
     assert EngineConfig.from_args(args).quant == "nf4p"
     args = ap.parse_args(["--quant", "luna_approx"])
     assert EngineConfig.from_args(args).quant is None
+    # "int4" is neither an engine mode nor a model-level one
+    from repro.core.layers import QuantConfig
+    args = ap.parse_args(["--quant", "int4"])
+    assert EngineConfig.from_args(args).quant is None
+    with pytest.raises(ValueError, match="int4"):
+        QuantConfig(mode=args.quant)
     args = ap.parse_args([])
     assert EngineConfig.from_args(args).quant is None

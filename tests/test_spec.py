@@ -109,6 +109,24 @@ def test_spec_token_identity(arch, paged, mode):
         assert m.spec_drafted > 0 and m.spec_ticks > 0
 
 
+@pytest.mark.parametrize("mode", ["ngram", "self_lut"])
+def test_spec_drafts_are_accepted(mode):
+    """On periodic prompts both proposers land some drafts: acceptance is
+    a rate in (0, 1] and the accounting reconciles.  Drafts that never
+    match the verifier would mean the proposer or the accept scan broke
+    (token identity alone cannot see it: the verifier's correction still
+    lands every token)."""
+    cfg, params = _setup()
+    prompts = [[7, 8, 9, 10] * 4, [3, 5] * 6, [11, 12, 13] * 3]
+    base, _ = _serve(cfg, params, prompts)
+    out, eng = _serve(cfg, params, prompts, spec=mode)
+    assert out == base
+    m = eng.metrics
+    assert 0 < m.spec_accepted <= m.spec_drafted
+    assert m.spec_accepted + m.spec_rejected == m.spec_drafted
+    assert 0.0 < m.summary(len(prompts))["spec_acceptance"] <= 1.0
+
+
 def test_spec_identity_moe_mla():
     """DeepSeek MLA attention + capacity-routed MoE: the verify window
     groups MoE dispatch by column so routing competition matches the

@@ -18,10 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.quant import QuantizedWeight
+from repro.core.quant import QuantizedWeight, quantize_weight
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.lut_gemm import ops as lut_ops
-from repro.kernels.lut_gemm.lut_gemm import (lut_gemm_dc, lut_gemm_dc_res,
+from repro.kernels.lut_gemm.lut_gemm import (lut_gemm_dc_res,
                                              lut_gemm_dc_res_experts)
 from repro.kernels.paged_attention.paged_attention import (
     paged_decode_attention)
@@ -88,37 +88,46 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("kernel", ["lut_gemm_dc", "lut_gemm_dc_res"])
+def _frozen(sds, quant, k, n):
+    """The weight ``quantize_weight`` freezes a (k, n) leaf to at
+    ``quant``, as shapes on one described chip (nothing is encoded)."""
+    frozen = jax.eval_shape(lambda w: quantize_weight(w, quant),
+                            jax.ShapeDtypeStruct((k, n), jnp.float32))
+    return jax.tree.map(lambda a: sds(a.shape, a.dtype), frozen)
+
+
+@pytest.mark.parametrize("quant", ["lut4", "nf4"])
 @pytest.mark.parametrize("k,n", LUT_SHAPES)
-def test_lut_gemm_compiles_for_v5e(sds, kernel, k, n):
-    """Decode-size LUT GEMMs with the block sizes the wrappers choose."""
-    bm, bn, bk = lut_ops.gemm_blocks(DECODE_M, k, n)
-    mp = DECODE_M + (-DECODE_M) % bm
-    kp, np_ = k + (-k) % bk, n + (-n) % bn
-    f32 = jnp.float32
-    x = sds((mp, kp), f32)
-    codes = sds((kp, np_), jnp.int8)
-    tab4, chan = sds((4,), f32), sds((np_,), f32)
-    if kernel == "lut_gemm_dc":
-        _compile(lambda *a: lut_gemm_dc(*a, bm=bm, bn=bn, bk=bk),
-                 x, codes, tab4, tab4, chan, chan)
-    else:
-        _compile(lambda *a: lut_gemm_dc_res(*a, bm=bm, bn=bn, bk=bk),
-                 x, codes, tab4, tab4, sds((16,), f32), chan, chan)
+def test_lut_gemm_compiles_for_v5e(sds, quant, k, n):
+    """Decode-size LUT GEMMs over each frozen form, with the block sizes
+    ``lut_matmul`` chooses."""
+    _compile(lut_ops.lut_matmul, sds((DECODE_M, k), jnp.float32),
+             _frozen(sds, quant, k, n))
+
+
+def test_lut4_decode_matmul_takes_fused_kernel_on_v5e(sds):
+    """Lowered for the TPU, an affine (lut4) decode matmul runs the same
+    fused Pallas kernel as NF4, ``lut_gemm_dc_res``."""
+    k, n = NF4_DECODE_SHAPES[0]
+    x = sds((NF4_DECODE_ROWS, 1, k), jnp.bfloat16)
+    compiled = _compile(lut_ops.quantized_matmul, x,
+                        _frozen(sds, "lut4", k, n))
+    assert "lut_gemm_dc_res" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < k * n * 4
 
 
 def _nf4_weight(sds, lead, k, n):
     f32 = jnp.float32
-    chan, tab4 = sds(lead + (n,), f32), sds(lead + (4,), f32)
-    return QuantizedWeight(sds(lead + (k, n), jnp.int8), chan, chan, tab4,
-                           tab4, sds(lead + (16,), f32), kernel="nf4_dc")
+    chan = sds(lead + (n,), f32)
+    return QuantizedWeight(sds(lead + (k, n), jnp.int8), chan, chan,
+                           sds(lead + (16,), f32))
 
 
 @pytest.mark.parametrize("rows,k,n", [
     *[(NF4_DECODE_ROWS, k, n) for k, n in NF4_DECODE_SHAPES],
     *[(DSV2_ROWS, k, n) for k, n in DSV2_DECODE_SHAPES]])
 def test_nf4_decode_matmul_takes_fused_kernel_on_v5e(sds, rows, k, n):
-    """Lowered for the TPU, an ``nf4_dc`` decode matmul runs the Pallas
+    """Lowered for the TPU, an NF4 decode matmul runs the Pallas
     LUT kernel and holds no float copy of the weight: its temporaries
     stay below one f32 (K, N) weight (the ``jnp`` path's mux tree needs
     about twenty)."""
@@ -254,10 +263,9 @@ def test_flash_attention_compiles_for_v5e(sds):
 
 
 @pytest.mark.parametrize("fn", [
-    lut_ops.nf4_matmul_kernel, lut_ops.lut4_matmul_kernel,
-    lut_ops.nf4dc_matmul_kernel, lut_ops.nf4_dc_matmul,
-    lut_ops.nf4_dc_expert_matmul, ssd_ops.ssd_chunked_kernel,
-    lut_gemm_dc, lut_gemm_dc_res, lut_gemm_dc_res_experts, ssd_scan,
+    lut_ops.lut_matmul, lut_ops.lut_expert_matmul,
+    ssd_ops.ssd_chunked_kernel, lut_gemm_dc_res, lut_gemm_dc_res_experts,
+    ssd_scan,
     flash_attention, paged_decode_attention,
 ], ids=lambda f: f.__name__)
 def test_kernels_do_not_default_to_interpret(fn):
